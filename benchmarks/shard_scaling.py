@@ -25,13 +25,15 @@ sharded bank) recording sustained QPS and emitting the per-shard
 ``shard_dispatch`` / ``shard_ingest`` / ``rebalance`` trace events that
 ``tools/check_trace.py --expect`` pins in CI.
 
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-      PYTHONPATH=src python -m benchmarks.shard_scaling [--smoke | --full]
-      [--trace-out FILE]
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.shard_scaling \\
+      [--smoke | --full] [--trace-out FILE]
 
-(The flag is set automatically when absent — it must reach the process
-before jax initializes its platform, which is why this module touches
-``os.environ`` before any jax import.)
+Under ``JAX_PLATFORMS=cpu`` the 8-device host flag
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``) is set when
+absent — it must reach the process before jax initializes its platform,
+which is why this module touches ``os.environ`` before any jax import.  On
+an accelerator the flag is left alone and the shard sweep stops at the
+visible device count.
 """
 from __future__ import annotations
 
@@ -43,7 +45,8 @@ from pathlib import Path
 # must precede ANY jax import: the host platform device count is fixed at
 # first jax initialization
 _FLAG = "--xla_force_host_platform_device_count"
-if _FLAG not in os.environ.get("XLA_FLAGS", ""):
+if (os.environ.get("JAX_PLATFORMS") == "cpu"
+        and _FLAG not in os.environ.get("XLA_FLAGS", "")):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "") + f" {_FLAG}=8"
     ).strip()
@@ -170,7 +173,8 @@ def run(full: bool = False, smoke: bool = False, trace_out=None):
     parity = {}
     projected = {}
     overhead = {}
-    sweep = SHARD_SWEEP if not smoke else (1, 8)
+    sweep = tuple(S for S in (SHARD_SWEEP if not smoke else (1, 8))
+                  if S <= len(jax.devices()))
     for S in sweep:
         mesh = make_bank_mesh(S)
         sharded = ShardedGPBank.from_bank(resident, mesh)

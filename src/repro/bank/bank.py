@@ -185,7 +185,7 @@ def _downdate_arrays(chol, b, sqrtlam, noise, Phi_rm, y_rm):
         return (L2, ok & ok2), None
 
     (chol, ok), _ = jax.lax.scan(one, (chol, jnp.bool_(True)), W)
-    b = b - Phi_rm.T @ y_rm
+    b = b - jnp.matmul(Phi_rm.T, y_rm, precision=jax.lax.Precision.HIGHEST)
     u = fagp._solve_mean_weights(chol, sqrtlam, b, sig2)
     return chol, b, u, ok
 

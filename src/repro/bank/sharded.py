@@ -154,7 +154,8 @@ def _binv_rows(chol_rows):
 @partial(jax.jit, static_argnames=("mesh",))
 def _sh_binv(chol, mesh):
     sh, rep = _bank_axis_specs(mesh)
-    return shardspec.shard_map(_binv_rows, mesh, (sh,), sh)(chol)
+    return jax.shard_map(_binv_rows, mesh=mesh, in_specs=(sh,),
+                         out_specs=sh, check_vma=False)(chol)
 
 
 @partial(jax.jit, static_argnames=("mesh", "backend", "block_rows"))
@@ -185,7 +186,8 @@ def _sh_fit(Xb, yb, maskb, spec, idx, aux, mesh, backend, block_rows):
     aux_specs = jax.tree_util.tree_map(lambda _: rep, aux)
     in_specs = (row_sh, row_sh, row_sh, rep, rep, rep, rep, aux_specs) + \
         (rep,) * len(omega_t)
-    return shardspec.shard_map(body, mesh, in_specs, (sh,) * 5)(
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=(sh,) * 5, check_vma=False)(
         Xb, yb, maskb, idx, spec.eps, spec.rho,
         jnp.asarray(spec.noise, jnp.float32), aux, *omega_t,
     )
@@ -207,7 +209,8 @@ def _sh_mean_var(binv, u_s, sqrtlam_s, lslots, Xq, spec, idx, mesh):
         return fagp._bank_gathered_posterior(binv_l, u_l, sq_l, sl_l, Phis)
 
     in_specs = (sh, sh, sh, sh, sh, rep, rep, rep) + (rep,) * len(omega_t)
-    return shardspec.shard_map(body, mesh, in_specs, (sh, sh))(
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=(sh, sh), check_vma=False)(
         binv, u_s, sqrtlam_s, lslots, Xq, idx, spec.eps, spec.rho, *omega_t,
     )
 
@@ -239,7 +242,8 @@ def _sh_update_scatter(chol_s, u_s, b_s, sqrtlam_s, binv, lslots, Xg, yg,
 
     in_specs = (sh, sh, sh, sh, sh, sh, sh, sh, sh, rep, rep, rep, rep) + \
         (rep,) * len(omega_t)
-    return shardspec.shard_map(body, mesh, in_specs, (sh,) * 4)(
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=(sh,) * 4, check_vma=False)(
         chol_s, u_s, b_s, sqrtlam_s, binv, lslots, Xg, yg, maskg,
         idx, spec.eps, spec.rho, jnp.asarray(spec.noise, jnp.float32),
         *omega_t,
@@ -273,7 +277,8 @@ def _sh_downdate_scatter(chol_s, u_s, b_s, sqrtlam_s, binv, lslots, Xg, yg,
 
     in_specs = (sh, sh, sh, sh, sh, sh, sh, sh, sh, rep, rep, rep, rep) + \
         (rep,) * len(omega_t)
-    return shardspec.shard_map(body, mesh, in_specs, (sh,) * 5)(
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=(sh,) * 5, check_vma=False)(
         chol_s, u_s, b_s, sqrtlam_s, binv, lslots, Xg, yg, maskg,
         idx, spec.eps, spec.rho, jnp.asarray(spec.noise, jnp.float32),
         *omega_t,
@@ -306,7 +311,8 @@ def _sh_refit_scatter(chol_s, u_s, b_s, lam_s, sqrtlam_s, binv, lslots,
         return chol_l, u_l, b_l, lam_l, sq_l, binv_l
 
     in_specs = (sh,) * 10 + (rep, rep, rep, rep) + (rep,) * len(omega_t)
-    return shardspec.shard_map(body, mesh, in_specs, (sh,) * 6)(
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=(sh,) * 6, check_vma=False)(
         chol_s, u_s, b_s, lam_s, sqrtlam_s, binv, lslots, Xg, yg, maskg,
         idx, spec.eps, spec.rho, jnp.asarray(spec.noise, jnp.float32),
         *omega_t,
@@ -343,7 +349,8 @@ def _sh_write_slot(chol_s, u_s, b_s, lam_s, sqrtlam_s, binv, gslot,
         return chol_l, u_l, b_l, lam_l, sq_l, binv_l
 
     in_specs = (sh,) * 6 + (rep,) * 6
-    return shardspec.shard_map(body, mesh, in_specs, (sh,) * 6)(
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=(sh,) * 6, check_vma=False)(
         chol_s, u_s, b_s, lam_s, sqrtlam_s, binv, gslot,
         chol, u, b, lam, sqrtlam,
     )
@@ -392,8 +399,6 @@ class ShardedGPBank:
                 " overlays (GPBank.optimize) have no shard-local serving "
                 "path yet — convert with to_bank() first"
             )
-        if not shardspec.has_shard_map():  # pragma: no cover - ancient jax
-            raise RuntimeError("this jax build lacks shard_map")
 
     # -- constructors -------------------------------------------------------
 

@@ -23,6 +23,10 @@ __all__ = ["ExactGPState", "KERNELS", "fit", "predict", "mean_var", "nlml"]
 # these via ``exact_kernel`` so the parity tests share one oracle table
 KERNELS = {"se": k_se_ard, "matern52": k_matern52_ard}
 
+# the float32 reference: its products run at full f32 precision on every
+# backend (a TPU's default for an f32 matmul is lower)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -53,10 +57,10 @@ def predict(state: ExactGPState, Xs: jax.Array):
     """Posterior mean (N*,) and covariance (N*, N*) at test inputs Xs."""
     k = KERNELS[state.kernel]
     Ks = k(Xs, state.X, state.params.eps)                 # (N*, N)
-    mu = Ks @ state.alpha                                  # Eq. 3, m = 0
+    mu = jnp.matmul(Ks, state.alpha, precision=_HIGHEST)   # Eq. 3, m = 0
     V = jax.scipy.linalg.solve_triangular(state.chol, Ks.T, lower=True)  # (N, N*)
     Kss = k(Xs, Xs, state.params.eps)
-    cov = Kss - V.T @ V                                    # Eq. 4
+    cov = Kss - jnp.matmul(V.T, V, precision=_HIGHEST)     # Eq. 4
     return mu, cov
 
 
@@ -67,7 +71,7 @@ def mean_var(state: ExactGPState, Xs: jax.Array):
     reference kernels are unit-variance, so the prior diagonal is 1."""
     k = KERNELS[state.kernel]
     Ks = k(Xs, state.X, state.params.eps)                  # (N*, N)
-    mu = Ks @ state.alpha
+    mu = jnp.matmul(Ks, state.alpha, precision=_HIGHEST)
     V = jax.scipy.linalg.solve_triangular(state.chol, Ks.T, lower=True)
     var = jnp.maximum(1.0 - jnp.sum(V * V, axis=0), 0.0)
     return mu, var

@@ -139,6 +139,11 @@ __all__ = [
     "register_backend",
 ]
 
+# Every f32 product of the model math runs at full f32 precision.  A TPU's
+# default for an f32 matmul rounds its inputs to bfloat16, and B's
+# conditioning (entries near N*lambda_0/sigma^2) amplifies that error.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _removed(old: str, new: str) -> None:
     raise TypeError(
@@ -602,8 +607,8 @@ def _block_scan_moments(X, y, feats_fn, M: int, block_rows: int,
         Xi, yi, mi = blk
         Phi_i = feats_fn(Xi) * mi[:, None]
         if want_gram:
-            G = G + Phi_i.T @ Phi_i
-        b = b + Phi_i.T @ _row_weight(mi, yi)
+            G = G + jnp.matmul(Phi_i.T, Phi_i, precision=_HIGHEST)
+        b = b + jnp.matmul(Phi_i.T, _row_weight(mi, yi), precision=_HIGHEST)
         return (G, b), None
 
     init = (jnp.zeros((M, M), X.dtype), jnp.zeros((M,) + y.shape[1:], X.dtype))
@@ -622,10 +627,18 @@ def _accumulate_moments(X, y, spec, idx, block_rows: int, row_mask=None):
     )
 
 
+@jax.jit
+def _factor(B, b, sqrtlam, sig2):
+    """The fit's M x M solve: Cholesky of B and the mean weights.  Jitted
+    on arrays alone, so every backend's fit shares this one program — at
+    paper scale (M = 14641) it is most of the fit's compile time."""
+    chol = jnp.linalg.cholesky(B)
+    return chol, _solve_mean_weights(chol, sqrtlam, b, sig2)
+
+
 def _finish_fit(B, b, loglam, sqrtlam, sig2, idx, params, Phi, y):
     """Shared fit epilogue: M x M Cholesky solve -> FAGPState."""
-    chol = jnp.linalg.cholesky(B)
-    u = _solve_mean_weights(chol, sqrtlam, b, sig2)
+    chol, u = _factor(B, b, sqrtlam, sig2)
     return FAGPState(
         idx=idx, lam=jnp.exp(loglam), sqrtlam=sqrtlam, chol=chol, u=u,
         params=params, Phi=Phi, y=y, b=b,
@@ -633,17 +646,22 @@ def _finish_fit(B, b, loglam, sqrtlam, sig2, idx, params, Phi, y):
 
 
 @jax.jit
-def _fit(X, y, spec: GPSpec, idx):
-    """jnp-backend fit: the spec's static metadata keys the jit cache, its
-    data leaves (eps/rho/noise/omega) are traced."""
-    exp = get_expansion(spec.expansion)
-    sig2 = spec.noise**2
-    loglam = exp.log_eigenvalues(idx, spec)
+def _jnp_system(X, y, spec: GPSpec, idx):
+    """jnp-backend scaled system (B, b, log lambda, sqrt lambda): the
+    spec's static metadata keys the jit cache, its data leaves
+    (eps/rho/noise/omega) are traced."""
+    loglam = get_expansion(spec.expansion).log_eigenvalues(idx, spec)
     G, b = _accumulate_moments(X, y, spec, idx, spec.block_rows)
-    B, sqrtlam = _assemble_scaled_system(G, loglam, sig2)
-    Phi = _features(X, idx, spec) if spec.store_train else None
-    return _finish_fit(B, b, loglam, sqrtlam, sig2, idx, spec.params,
-                       Phi, y if spec.store_train else None)
+    B, sqrtlam = _assemble_scaled_system(G, loglam, spec.noise**2)
+    return B, b, loglam, sqrtlam
+
+
+def _fit(X, y, spec: GPSpec, idx):
+    """jnp-backend fit: streamed moments, then the shared solve."""
+    B, b, loglam, sqrtlam = _jnp_system(X, y, spec, idx)
+    Phi = _features_jit(X, spec, idx) if spec.store_train else None
+    return _finish_fit(B, b, loglam, sqrtlam, spec.noise**2, idx,
+                       spec.params, Phi, y if spec.store_train else None)
 
 
 def _pallas_streamed_bt(X, Y, consts, table, spec, tile):
@@ -662,14 +680,13 @@ def _pallas_streamed_bt(X, Y, consts, table, spec, tile):
 
 
 @jax.jit
-def _fit_pallas(X, y, spec: GPSpec, idx, aux):
-    """fit() on the streaming fused Pallas kernel: feature tiles are
-    generated on the fly inside the Gram accumulation (kernels/phi_gram) by
-    the expansion's tile builder, so Phi never exists in HBM and peak live
-    memory is O(M^2) in N — one HBM pass over X instead of the materialized
-    path's two passes plus an N x M intermediate.  (store_train=True
-    additionally materializes Phi for mode='paper' prediction,
-    reintroducing the N x M buffer by request.)
+def _pallas_system(X, y, spec: GPSpec, idx, aux):
+    """The scaled system on the streaming fused Pallas kernel: feature
+    tiles are generated on the fly inside the Gram accumulation
+    (kernels/phi_gram) by the expansion's tile builder, so Phi never exists
+    in HBM and peak live memory is O(M^2) in N — one HBM pass over X
+    instead of the materialized path's two passes plus an N x M
+    intermediate.
 
     Multi-output y (N, T): the shared scaled Gram B comes from the fused
     kernel exactly as in the single-output case; the per-task moment vectors
@@ -680,21 +697,27 @@ def _fit_pallas(X, y, spec: GPSpec, idx, aux):
     from repro.kernels import ops as kops
 
     exp = get_expansion(spec.expansion)
-    sig2 = spec.noise**2
     loglam = exp.log_eigenvalues(idx, spec)
     sqrtlam = jnp.exp(0.5 * loglam)
     consts = exp.tile_consts(spec)
     table = exp.tile_table(aux, spec)
     tile = exp.tile_fn()
     y0 = y if y.ndim == 1 else y[:, 0]
-    B, b = kops.fused_fit_moments(X, y0, consts, table, sqrtlam, sig2,
-                                  n_max=spec.n, tile_fn=tile)
+    B, b = kops.fused_fit_moments(X, y0, consts, table, sqrtlam,
+                                  spec.noise**2, n_max=spec.n, tile_fn=tile)
     if y.ndim == 2:
         b = _pallas_streamed_bt(X, y, consts, table, spec, tile)
-    Phi = (kops.expansion_phi(X, consts, table, n_max=spec.n, tile_fn=tile)
-           if spec.store_train else None)
-    return _finish_fit(B, b, loglam, sqrtlam, sig2, idx, spec.params,
-                       Phi, y if spec.store_train else None)
+    return B, b, loglam, sqrtlam
+
+
+def _fit_pallas(X, y, spec: GPSpec, idx, aux):
+    """fit() on the Pallas backend: the fused-kernel system, then the
+    shared solve.  (store_train=True additionally materializes Phi for
+    mode='paper' prediction, reintroducing the N x M buffer by request.)"""
+    B, b, loglam, sqrtlam = _pallas_system(X, y, spec, idx, aux)
+    Phi = _pallas_features(X, spec, idx, aux) if spec.store_train else None
+    return _finish_fit(B, b, loglam, sqrtlam, spec.noise**2, idx,
+                       spec.params, Phi, y if spec.store_train else None)
 
 
 # ---------------------------------------------------------------------------
@@ -856,7 +879,10 @@ def _jnp_fit(X, y, idx, aux, spec: "GPSpec"):
 
 
 def _jnp_mean_var(state, Xs, aux):
-    return _mean_var_jnp(state, Xs)
+    return _posterior_mean_var(
+        state.u, state.chol, state.sqrtlam,
+        _features_jit(Xs, state.spec, state.idx),
+    )
 
 
 # --- bank (multi-tenant) hooks ---------------------------------------------
@@ -891,7 +917,8 @@ def _bank_gathered_posterior(binv_s, u_s, sqrtlam_s, slots, Phis):
     -> (mu (Q,), var (Q,))."""
     mu = jnp.sum(Phis * u_s[slots], axis=1)
     PhisD = Phis * sqrtlam_s[slots]                      # (Q, M)
-    var = jnp.einsum("qm,qmn,qn->q", PhisD, binv_s[slots], PhisD)
+    var = jnp.einsum("qm,qmn,qn->q", PhisD, binv_s[slots], PhisD,
+                     precision=_HIGHEST)
     return mu, var
 
 
@@ -965,7 +992,10 @@ def _pallas_fit(X, y, idx, aux, spec: "GPSpec"):
 
 
 def _pallas_mean_var(state, Xs, aux):
-    return _mean_var_pallas(state, Xs, aux)
+    return _posterior_mean_var(
+        state.u, state.chol, state.sqrtlam,
+        _pallas_features(Xs, state.spec, state.idx, aux),
+    )
 
 
 def _pallas_bank_moments(Xb, yb, spec, idx, aux, block_rows, maskb=None):
@@ -1092,17 +1122,17 @@ def _update_arrays(chol, b, sqrtlam, noise, Phi_new, y_new):
         # K comparable to M: the rank-1 sweep is K*M sequential latency-bound
         # steps; rebuilding the M x M factor is O(M^3/3) fully-parallel work
         # and still never touches the original N rows
-        B = chol @ chol.T + W.T @ W
+        B = (jnp.matmul(chol, chol.T, precision=_HIGHEST)
+             + jnp.matmul(W.T, W, precision=_HIGHEST))
         chol = jnp.linalg.cholesky(B)
-    b = b + Phi_new.T @ y_new
+    b = b + jnp.matmul(Phi_new.T, y_new, precision=_HIGHEST)
     u = _solve_mean_weights(chol, sqrtlam, b, sig2)
     return chol, b, u
 
 
-@jax.jit
-def _update_state(state: FAGPState, Phi_new: jax.Array, y_new: jax.Array):
-    return _update_arrays(state.chol, state.b, state.sqrtlam,
-                          state.params.noise, Phi_new, y_new)
+# jitted on arrays, not on the state, so sessions that differ only in
+# backend share one compiled update
+_update_arrays_jit = jax.jit(_update_arrays)
 
 
 def fit_update(
@@ -1137,7 +1167,8 @@ def fit_update(
     backend = _check_backend_support(spec)
     aux = _backend_aux(backend, state.idx, spec)
     Phi_new = backend.features(X_new, spec, state.idx, aux)
-    chol, b, u = _update_state(state, Phi_new, y_new)
+    chol, b, u = _update_arrays_jit(state.chol, state.b, state.sqrtlam,
+                                    state.params.noise, Phi_new, y_new)
     Phi = y = None
     if state.Phi is not None:
         Phi = jnp.concatenate([state.Phi, Phi_new], axis=0)
@@ -1157,10 +1188,10 @@ def _predict_fused(state: FAGPState, Xs: jax.Array):
     Phi* Lbar^{-1} Phi*^T = (Phi* D) B^{-1} (Phi* D)^T via triangular solve.
     """
     Phis = _features(Xs, state.idx, state.spec)  # (N*, M)
-    mu = Phis @ state.u
+    mu = jnp.matmul(Phis, state.u, precision=_HIGHEST)
     PhisD = Phis * state.sqrtlam[None, :]
     V = jax.scipy.linalg.solve_triangular(state.chol, PhisD.T, lower=True)  # (M, N*)
-    cov = V.T @ V
+    cov = jnp.matmul(V.T, V, precision=_HIGHEST)
     return mu, cov
 
 
@@ -1220,34 +1251,27 @@ def predict(state: FAGPState, Xs: jax.Array, cfg: Any = None,
 
 
 @jax.jit
-def _mean_var_pallas(state: FAGPState, Xs, aux):
-    from repro.kernels import ops as kops
+def _posterior_mean_var(u, chol, sqrtlam, Phis):
+    """Posterior mean and marginal variance from query features Phis
+    (N*, M) — the one home of the single-model serving math, shared by
+    every backend's ``mean_var`` (only the feature construction differs,
+    as in the bank's ``_bank_gathered_posterior``):
 
-    spec = state.spec
-    exp = get_expansion(spec.expansion)
-    Phis = kops.expansion_phi(
-        Xs, exp.tile_consts(spec), exp.tile_table(aux, spec),
-        n_max=spec.n, tile_fn=exp.tile_fn(),
-    )
-    mu = Phis @ state.u
-    M = state.chol.shape[0]
-    Binv = jax.scipy.linalg.cho_solve((state.chol, True), jnp.eye(M, dtype=Phis.dtype))
-    var = kops.diag_quad(Phis * state.sqrtlam[None, :], Binv)
-    return mu, var
+        var = diag(Phis D B^{-1} D Phis^T) = colsum(V * V),
+        V = L^{-1} (Phis D)^T, L = chol(B)  — one triangular solve with
+        N* columns.
 
-
-@jax.jit
-def _mean_var_jnp(state: FAGPState, Xs):
-    Phis = _features(Xs, state.idx, state.spec)
-    mu = Phis @ state.u
-    PhisD = Phis * state.sqrtlam[None, :]
-    V = jax.scipy.linalg.solve_triangular(state.chol, PhisD.T, lower=True)
+    Never forms B^{-1}: at M = 14641 the M x M inverse is ~M^3 work per
+    call, and the TPU compiler needs more HBM than a v5e holds for it."""
+    mu = jnp.matmul(Phis, u, precision=_HIGHEST)
+    PhisD = Phis * sqrtlam[None, :]
+    V = jax.scipy.linalg.solve_triangular(chol, PhisD.T, lower=True)
     return mu, jnp.sum(V * V, axis=0)
 
 
 def predict_mean_var(state: FAGPState, Xs: jax.Array, cfg: Any = None):
     """Posterior mean and *marginal variance* (N*,) — the production serving
-    path: never materializes the N* x N* covariance (kernels/diag_quad).
+    path: never materializes the N* x N* covariance.
 
     Mean is (N*,) or (N*, T) for multi-output states; the variance is shared
     across tasks.  Expansion, backend and n_max derive from the baked spec."""

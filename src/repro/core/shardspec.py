@@ -5,49 +5,17 @@ bank-axis sharding (``bank.sharded``) rebuild a :class:`~repro.core.fagp.GPSpec`
 from shard-local leaves inside a ``shard_map`` body, probe mesh sizes, and
 thread the optional spectral-draw leaf as a ``*args`` tail.  This module is
 the single home for that glue — a third copy-paste was the alternative.
-
-It also owns the version-compat ``shard_map`` entry point: ``jax.shard_map``
-(new jax, ``check_vma``) when present, else the long-stable
-``jax.experimental.shard_map.shard_map`` (``check_rep``) — which is why the
-bank sharding runs on every jax the repo supports, unlike the
-``AxisType``/``jax.set_mesh`` machinery that gates the distributed tests.
 """
 from __future__ import annotations
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .fagp import GPSpec
 
-__all__ = [
-    "shard_map", "has_shard_map", "spec_local", "omega_args", "mesh_size",
-    "axis_size",
-]
-
-
-if hasattr(jax, "shard_map"):
-    def shard_map(f, mesh, in_specs, out_specs):
-        """Version-compat shard_map (new jax: top-level, check_vma)."""
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:  # jax < 0.6: experimental module, check_rep spelling
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-        def shard_map(f, mesh, in_specs, out_specs):
-            """Version-compat shard_map (old jax: experimental, check_rep)."""
-            return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, check_rep=False)
-    except ImportError:  # pragma: no cover - ancient jax
-        shard_map = None
-
-
-def has_shard_map() -> bool:
-    """True when this jax can run the repo's shard_map schedules."""
-    return shard_map is not None
+__all__ = ["spec_local", "omega_args", "mesh_size", "axis_size"]
 
 
 def spec_local(spec: GPSpec, eps, rho, omega) -> GPSpec:

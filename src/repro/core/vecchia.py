@@ -162,7 +162,8 @@ def _mean_var(X, y2, Xs, eps, noise, *, kernel, k, block_q, block_t):
         alpha = jax.vmap(
             lambda Lc, bc: jax.scipy.linalg.cho_solve((Lc, True), bc)
         )(L, yn)
-        mu = jnp.einsum("bk,bkt->bt", ks, alpha)
+        mu = jnp.einsum("bk,bkt->bt", ks, alpha,
+                        precision=jax.lax.Precision.HIGHEST)
         w = jax.vmap(
             lambda Lc, c: jax.scipy.linalg.solve_triangular(
                 Lc, c, lower=True
@@ -214,7 +215,8 @@ def _nlml(X, y2, eps, noise, *, kernel, k, block_q, block_t):
         alpha = jax.vmap(
             lambda Lc, bc: jax.scipy.linalg.cho_solve((Lc, True), bc)
         )(L, mi[:, :, None] * yc)
-        mu = jnp.einsum("bk,bkt->bt", c, alpha)                # (B, T)
+        mu = jnp.einsum("bk,bkt->bt", c, alpha,                # (B, T)
+                        precision=jax.lax.Precision.HIGHEST)
         w = jax.vmap(
             lambda Lc, cc: jax.scipy.linalg.solve_triangular(
                 Lc, cc, lower=True

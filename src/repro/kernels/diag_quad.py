@@ -26,7 +26,8 @@ def _diag_quad_body(a1_ref, c_ref, a2_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    t = jnp.dot(a1_ref[...], c_ref[...], preferred_element_type=jnp.float32)
+    t = jnp.dot(a1_ref[...], c_ref[...], precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
     o_ref[...] += jnp.sum(t * a2_ref[...], axis=1)[None, :]
 
 

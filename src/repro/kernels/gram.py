@@ -34,6 +34,7 @@ def _gram_body(phi_i_ref, phi_j_ref, di_ref, dj_ref, sig2_ref, o_ref, *, nk: int
     # (TI, TJ) += Phi_k_i^T @ Phi_k_j   (f32 accumulation on the MXU)
     o_ref[...] += jax.lax.dot_general(
         phi_i_ref[...], phi_j_ref[...], (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 

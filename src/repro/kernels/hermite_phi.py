@@ -69,6 +69,7 @@ def phi_tile(xt, consts, s, *, p: int, n_max: int):
         # (TN, TM) <- feats^T @ S_j  : MXU-friendly "gather"
         sel = jax.lax.dot_general(
             feats, s_j, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
         out = sel if out is None else out * sel

@@ -32,7 +32,8 @@ def sq_dists(Xq: jax.Array, Xt: jax.Array) -> jax.Array:
     """Squared euclidean distances (Bq, Bt) between two point blocks."""
     q2 = jnp.sum(Xq * Xq, axis=1)[:, None]
     t2 = jnp.sum(Xt * Xt, axis=1)[None, :]
-    return jnp.maximum(q2 + t2 - 2.0 * (Xq @ Xt.T), 0.0)
+    cross = jnp.matmul(Xq, Xt.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(q2 + t2 - 2.0 * cross, 0.0)
 
 
 def _train_blocks(Xt: jax.Array, block_t: int):

@@ -2,13 +2,12 @@
 
 Handles: padding to tile multiples, transposition to the kernel layouts,
 interpret-mode resolution (CPU -> interpret=True so the kernel body runs in
-Python; TPU -> compiled), and jnp fallbacks for tiny shapes where kernel
-tiling overhead is not worth it.
+Python; TPU -> compiled; any other backend raises), and jnp fallbacks for
+tiny shapes where kernel tiling overhead is not worth it.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -27,12 +26,22 @@ __all__ = [
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """interpret=None -> run in interpret mode unless actually on TPU."""
+    """interpret=None -> compiled kernels on TPU, interpret mode on CPU.
+
+    Any other backend raises: the kernels are written for the TPU, and
+    running them interpreted there would hide the device behind a slow
+    emulation with no error."""
     if interpret is not None:
         return interpret
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return os.environ["REPRO_PALLAS_INTERPRET"] != "0"
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels run compiled on 'tpu' and interpreted on "
+        f"'cpu'; the default backend is {backend!r} (use backend='jnp')"
+    )
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:

@@ -70,6 +70,7 @@ def _phi_gram_body(
 
     o_ref[...] += jax.lax.dot_general(
         phi_i, phi_j, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -82,6 +83,7 @@ def _phi_gram_body(
         # (1, TI) += y_k @ Phi_k_i  (y already zero-padded past N)
         b_ref[...] += jax.lax.dot_general(
             y_ref[...], phi_i, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
 
@@ -168,6 +170,7 @@ def _bank_phi_gram_body(
 
     o_ref[...] += jax.lax.dot_general(
         phi_i, phi_j, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )[None]
 
@@ -183,6 +186,7 @@ def _bank_phi_gram_body(
         # row-validity masks the bank emits, the two are identical
         b_ref[...] += jax.lax.dot_general(
             y_ref[0] * mask, phi_i, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )[None]
 

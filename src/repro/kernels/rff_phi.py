@@ -49,6 +49,7 @@ def rff_tile(xt, consts, table, *, p: int, n_max: int):
     phase = table[p : p + 1, :]                         # (1, TM)
     z = jax.lax.dot_general(
         xt, w, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )                                                   # (TK, TM)
     return jnp.cos(z + phase)

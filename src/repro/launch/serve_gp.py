@@ -42,6 +42,7 @@ from repro.bank import (
 from repro.core import fagp
 from repro.core.gp import GP, GPSpec
 from repro.data import make_gp_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import (
     NULL,
     NULL_TRACER,
@@ -497,6 +498,7 @@ def main():
                     help="arm the recompile watchdog over the serving "
                          "executables (fleet mode only)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.fleet:
         obs_on = (args.metrics_port is not None or args.trace_out
                   or args.watchdog)

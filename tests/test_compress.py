@@ -2,7 +2,6 @@
 import numpy as np
 import jax
 import jax.numpy as jnp
-import pytest
 from hypcompat import given, settings, st  # hypothesis, or fixed examples
 
 from repro.parallel import compress
@@ -42,10 +41,6 @@ class TestQuantize:
 
 
 class TestCompressedAllReduce:
-    @pytest.mark.skipif(
-        not hasattr(jax.sharding, "AxisType"),
-        reason="mesh AxisType/shard_map API unavailable in this jax version",
-    )
     def test_matches_mean_of_shards(self):
         """On a 1-device mesh the compressed all-reduce == dequantized value;
         residual carries the quantization error."""

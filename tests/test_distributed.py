@@ -6,16 +6,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
-
-# every body builds a mesh via launch.mesh.make_local_mesh and runs under
-# jax.set_mesh; skip (not fail) on jax versions predating that API, same as
-# the shard_map guard in test_compress
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType") or not hasattr(jax, "set_mesh"),
-    reason="mesh AxisType/set_mesh API unavailable in this jax version",
-)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

@@ -25,6 +25,26 @@ def _setup(N, p, n_max, kind="full", degree=None, seed=0):
     return X, eps, rho, idx, consts, S
 
 
+class TestResolveInterpret:
+    @pytest.mark.parametrize("backend,expected", [("cpu", True),
+                                                  ("tpu", False)])
+    def test_default_follows_backend(self, monkeypatch, backend, expected):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert ops.resolve_interpret(None) is expected
+
+    def test_other_backends_are_refused(self, monkeypatch):
+        """No silent interpret fallback on an accelerator the kernels are
+        not written for."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops.resolve_interpret(None)
+
+    def test_explicit_choice_wins(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        assert ops.resolve_interpret(True) is True
+        assert ops.resolve_interpret(False) is False
+
+
 class TestHermitePhi:
     @pytest.mark.parametrize(
         "N,p,n_max",

@@ -7,7 +7,6 @@ import threading
 import numpy as np
 import jax
 import jax.numpy as jnp
-import pytest
 
 from repro import checkpoint, optim
 from repro.data import TokenStream
@@ -131,10 +130,6 @@ class TestTrainLoop:
         assert rep["final_step"] < 10_000
         assert checkpoint.latest_step(d) == rep["final_step"]
 
-    @pytest.mark.skipif(
-        not hasattr(jax.sharding, "AxisType"),
-        reason="mesh AxisType API unavailable in this jax version",
-    )
     def test_elastic_restore_resharding(self, tmp_path):
         """Checkpoint written unsharded restores onto a live mesh sharding."""
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -182,3 +177,31 @@ class TestOptim:
         assert not np.array_equal(
             np.asarray(s1.batch(7)["tokens"]), np.asarray(s1.batch(8)["tokens"])
         )
+
+
+class TestCompileCache:
+    """The entry points' persistent compilation cache
+    (``launch/compile_cache.py``): JAX's own directory when
+    ``JAX_COMPILATION_CACHE_DIR`` is set, else ``<checkout>/.jax_cache``."""
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        from repro.launch.compile_cache import enable_compile_cache
+
+        was = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+
+    def test_default_is_the_checkout(self, monkeypatch):
+        from pathlib import Path
+
+        from repro.launch.compile_cache import enable_compile_cache
+
+        was = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            got = enable_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+        assert Path(got) == Path(__file__).resolve().parents[1] / ".jax_cache"

@@ -15,15 +15,6 @@ import textwrap
 
 import pytest
 
-from repro.core import shardspec
-
-# mirror of test_distributed's AxisType/set_mesh version guard, but on the
-# (older, wider) shard_map availability the sharded bank actually needs
-pytestmark = pytest.mark.skipif(
-    not shardspec.has_shard_map(),
-    reason="no shard_map API in this jax version",
-)
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # shared subprocess preamble: a 16-tenant fleet, a resident bank, and its
