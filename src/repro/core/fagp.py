@@ -105,6 +105,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import span
 from .approximation import (
     Approximation,
     UnsupportedError,
@@ -638,7 +639,8 @@ def _factor(B, b, sqrtlam, sig2):
 
 def _finish_fit(B, b, loglam, sqrtlam, sig2, idx, params, Phi, y):
     """Shared fit epilogue: M x M Cholesky solve -> FAGPState."""
-    chol, u = _factor(B, b, sqrtlam, sig2)
+    with span("fagp.factor"):
+        chol, u = _factor(B, b, sqrtlam, sig2)
     return FAGPState(
         idx=idx, lam=jnp.exp(loglam), sqrtlam=sqrtlam, chol=chol, u=u,
         params=params, Phi=Phi, y=y, b=b,
@@ -658,7 +660,8 @@ def _jnp_system(X, y, spec: GPSpec, idx):
 
 def _fit(X, y, spec: GPSpec, idx):
     """jnp-backend fit: streamed moments, then the shared solve."""
-    B, b, loglam, sqrtlam = _jnp_system(X, y, spec, idx)
+    with span("fagp.system"):
+        B, b, loglam, sqrtlam = _jnp_system(X, y, spec, idx)
     Phi = _features_jit(X, spec, idx) if spec.store_train else None
     return _finish_fit(B, b, loglam, sqrtlam, spec.noise**2, idx,
                        spec.params, Phi, y if spec.store_train else None)
@@ -714,7 +717,8 @@ def _fit_pallas(X, y, spec: GPSpec, idx, aux):
     """fit() on the Pallas backend: the fused-kernel system, then the
     shared solve.  (store_train=True additionally materializes Phi for
     mode='paper' prediction, reintroducing the N x M buffer by request.)"""
-    B, b, loglam, sqrtlam = _pallas_system(X, y, spec, idx, aux)
+    with span("fagp.system"):
+        B, b, loglam, sqrtlam = _pallas_system(X, y, spec, idx, aux)
     Phi = _pallas_features(X, spec, idx, aux) if spec.store_train else None
     return _finish_fit(B, b, loglam, sqrtlam, spec.noise**2, idx,
                        spec.params, Phi, y if spec.store_train else None)
@@ -878,13 +882,6 @@ def _jnp_fit(X, y, idx, aux, spec: "GPSpec"):
     return _fit(X, y, spec, idx)
 
 
-def _jnp_mean_var(state, Xs, aux):
-    return _posterior_mean_var(
-        state.u, state.chol, state.sqrtlam,
-        _features_jit(Xs, state.spec, state.idx),
-    )
-
-
 # --- bank (multi-tenant) hooks ---------------------------------------------
 # One stacked FAGPState holds B independent fitted sessions (leading bank
 # axis on chol/u/b/lam/sqrtlam; idx/params/spec shared).  ``bank_moments``
@@ -939,6 +936,19 @@ def _jnp_bank_moments(Xb, yb, spec, idx, aux, block_rows, maskb=None):
     return _jnp_bank_moments_jit(Xb, yb, spec, idx, block_rows, maskb)
 
 
+def _single_mean_var(features):
+    """Build a backend's ``mean_var`` from its feature map: the serving
+    math is ``_posterior_mean_var`` for every backend (as the bank's is
+    ``_bank_gathered_posterior``), only the features differ."""
+    def f(state, Xs, aux):
+        with span("fagp.features"):
+            Phis = features(Xs, state.spec, state.idx, aux)
+        with span("fagp.posterior"):
+            return _posterior_mean_var(state.u, state.chol, state.sqrtlam,
+                                       Phis)
+    return f
+
+
 def _gathered_bank_mean_var(features):
     """Build a ``bank_mean_var`` from a backend's feature map: the gathered
     serving path is backend-independent (one home, above) — only the
@@ -991,13 +1001,6 @@ def _pallas_fit(X, y, idx, aux, spec: "GPSpec"):
     return _fit_pallas(X, y, spec, idx, aux)
 
 
-def _pallas_mean_var(state, Xs, aux):
-    return _posterior_mean_var(
-        state.u, state.chol, state.sqrtlam,
-        _pallas_features(Xs, state.spec, state.idx, aux),
-    )
-
-
 def _pallas_bank_moments(Xb, yb, spec, idx, aux, block_rows, maskb=None):
     """One kernel launch for the whole bank: the bank axis is a leading
     grid dimension of the streaming fused kernel, so feature tiles for
@@ -1015,13 +1018,13 @@ def _pallas_bank_moments(Xb, yb, spec, idx, aux, block_rows, maskb=None):
 
 register_backend(FitBackend(
     name="jnp", prepare=lambda idx_np, spec: None, fit=_jnp_fit,
-    features=_jnp_features, mean_var=_jnp_mean_var, moments=_jnp_moments,
-    bank_moments=_jnp_bank_moments,
+    features=_jnp_features, mean_var=_single_mean_var(_jnp_features),
+    moments=_jnp_moments, bank_moments=_jnp_bank_moments,
     bank_mean_var=_gathered_bank_mean_var(_jnp_features),
 ))
 register_backend(FitBackend(
     name="pallas", prepare=_pallas_prepare, fit=_pallas_fit,
-    features=_pallas_features, mean_var=_pallas_mean_var,
+    features=_pallas_features, mean_var=_single_mean_var(_pallas_features),
     moments=_pallas_moments, supports=_pallas_supports,
     bank_moments=_pallas_bank_moments,
     bank_mean_var=_gathered_bank_mean_var(_pallas_features),
@@ -1055,9 +1058,10 @@ def fit(X: jax.Array, y: jax.Array, spec: GPSpec, cfg: Any = None) -> FAGPState:
         )
     _check_p(spec, X.shape[1])
     backend = _check_backend_support(spec)
-    idx_np = spec.indices(X.shape[1])
-    idx = jnp.asarray(idx_np)
-    aux = backend.prepare(idx_np, spec)
+    with span("fagp.fit.prepare"):
+        idx_np = spec.indices(X.shape[1])
+        idx = jnp.asarray(idx_np)
+        aux = backend.prepare(idx_np, spec)
     state = backend.fit(X, y, idx, aux, spec)
     return dataclasses.replace(state, spec=spec)
 

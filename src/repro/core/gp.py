@@ -63,6 +63,7 @@ from typing import Any, Callable, Optional
 
 import jax
 
+from ..obs.trace import span
 from . import fagp  # noqa: F401  (fagp registers both families on import)
 from .approximation import (
     Approximation,
@@ -104,9 +105,10 @@ class GP:
     def fit(cls, X: jax.Array, y: jax.Array, spec: GPSpec) -> "GP":
         """Fit the posterior; y is (N,) or (N, T) for T tasks sharing one
         factorization.  The spec is baked into the session."""
-        ap = _approx_for(spec)
-        require_capability(ap, "fit", spec)
-        return cls(state=ap.fit(X, y, spec))
+        with span("gp.fit"):
+            ap = _approx_for(spec)
+            require_capability(ap, "fit", spec)
+            return cls(state=ap.fit(X, y, spec))
 
     @classmethod
     def from_state(cls, state) -> "GP":
@@ -190,9 +192,10 @@ class GP:
 
     def mean_var(self, Xs: jax.Array):
         """Posterior mean and marginal variance — the serving path."""
-        ap = self.approximation
-        require_capability(ap, "mean_var", self.spec)
-        return ap.mean_var(self.state, Xs)
+        with span("gp.mean_var"):
+            ap = self.approximation
+            require_capability(ap, "mean_var", self.spec)
+            return ap.mean_var(self.state, Xs)
 
     def update(self, X_new: jax.Array, y_new: jax.Array) -> "GP":
         """Absorb new observations (FAGP: rank-k Cholesky update; vecchia:

@@ -10,7 +10,10 @@ Usage::
 
 Each ``span`` emits ONE Chrome-trace *complete* event (``"ph": "X"``) at
 exit, stamped from ``time.perf_counter_ns`` (monotonic — wall-clock
-adjustments can never produce negative durations).  ``instant`` emits a
+adjustments can never produce negative durations).  It also brackets a
+``jax.profiler.TraceAnnotation`` of the same name (never its arguments), so
+under a running profiler the span lands on the trace's ``/host:CPU`` plane,
+on the same clock as the device's ops.  ``instant`` emits a
 zero-duration marker (``"ph": "i"``) for point events like hyperopt
 progress callbacks.  Events carry the emitting thread id, so the
 dispatcher thread and the caller thread render as separate tracks and
@@ -26,16 +29,23 @@ singleton whose ``__enter__``/``__exit__`` do nothing, so instrumented
 code costs one method call when tracing is off.  Hot-path call sites pass
 no kwargs (kwargs would build a dict even for the null tracer); per-block
 sites may attach bucket/tenant attributes freely.
+
+:func:`span` is the bare profiler span for code that takes no tracer (the
+single-GP ``GP.fit`` / ``GP.mean_var`` path): a ``TraceAnnotation`` with
+no buffer, under a microsecond when no profiler runs.  Per-call sites name
+it with a constant and pass no arguments — the profiler would encode them
+into the event's name (``name#k=v#``), giving every call a different one.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
 import time
 from typing import Optional
 
-__all__ = ["Tracer", "NullTracer", "NULL_TRACER", "SPAN_SCHEMA_KEYS"]
+__all__ = ["Tracer", "NullTracer", "NULL_TRACER", "SPAN_SCHEMA_KEYS", "span"]
 
 # required keys of every emitted event — tools/check_trace.py validates
 # emitted JSONL against exactly this contract
@@ -44,13 +54,28 @@ SPAN_SCHEMA_KEYS = ("name", "ph", "ts", "pid", "tid")
 _PID = os.getpid()
 
 
+@functools.cache
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+def span(name: str):
+    """A profiler span named ``name`` (a ``with`` block): recorded only
+    while a JAX profiler trace runs, with no buffer of its own."""
+    return _annotation()(name)
+
+
 class _Span:
     """Context manager recording one complete event on exit.  The buffer
     holds compact tuples ``("X", name, t0_ns, t1_ns, tid, args)`` — the
     JSON dict is only built at export time, keeping the record path to
-    two clock reads, one tuple, and one list append under the lock."""
+    two clock reads, one tuple, and one list append under the lock —
+    plus the profiler annotation of the same name."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
         self._tracer = tracer
@@ -58,11 +83,14 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._ann = _annotation()(self._name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(exc_type, exc, tb)
         tr = self._tracer
         with tr._lock:
             if len(tr._events) < tr._limit:
