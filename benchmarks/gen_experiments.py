@@ -493,11 +493,11 @@ numbers are preserved in `experiments/dryrun_baseline/`.
 
 ## §Streaming fused fit + online updates
 
-`fit(backend="pallas")` runs the streaming fused-fit kernel
-(`src/repro/kernels/phi_gram.py`): Hermite-feature tiles are generated in
-VMEM inside the Gram accumulation, so the N×M feature matrix Phi is never
-written to HBM — one HBM pass over X, O(M²) live memory in N (pinned by
-`tests/test_streaming_fit.py`'s jaxpr sweep).  `fit_update` absorbs new
+`fit(backend="pallas")` runs two Pallas kernels per row chunk
+(`src/repro/kernels/ops.py::fused_fit_moments`): the features kernel writes
+the chunk's Phi once, the Gram kernel (`src/repro/kernels/gram.py`) adds
+its moments.  A chunk holds at most M (padded) rows, so live memory is
+O(M²) in N (pinned by `tests/test_streaming_fit.py`'s jaxpr sweep).  `fit_update` absorbs new
 observations by a rank-k Cholesky update, O(k M²) with no pass over the
 original N rows.  Measure both:
 

@@ -177,8 +177,9 @@ def fit_distributed(X, y, spec, *args):
     ``fit_distributed(X, y, spec, mesh)``.  ``spec.backend`` selects the
     per-shard engine via the core.fagp registry: 'jnp' runs the v1 pjit
     schedule, anything else runs the v2 shard_map schedule with that
-    backend's streaming moments kernel per shard (e.g. 'pallas' = fused
-    phi+gram, Phi never materialized — for any registered expansion).
+    backend's streaming moments kernel per shard (e.g. 'pallas' = the
+    features and Gram kernels chunk by chunk, O(M^2) live memory — for
+    any registered expansion).
 
     The split ``fit_distributed(X, y, params, cfg, mesh)`` form was removed.
     """

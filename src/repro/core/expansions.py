@@ -21,9 +21,9 @@ eigen-expansion.  A :class:`KernelExpansion` supplies:
 * a tile-level feature generator for the Pallas kernels — a module-level
   ``tile_fn(xt, consts, table, *, p, n_max)`` plus the ``tile_consts`` /
   ``tile_table`` arrays it consumes — usable both for standalone feature
-  construction (``kernels.ops.expansion_phi``) and inside the streaming
-  fused-fit kernel (``kernels.phi_gram``), so every expansion fits without
-  materializing the N x M Phi;
+  construction (``kernels.ops.expansion_phi``), for the fit's row chunks
+  (``kernels.ops.fused_fit_moments``) and inside the bank's fused kernel
+  (``kernels.phi_gram``), so every expansion fits in O(M^2) live memory;
 * an exact-kernel oracle — ``exact_kernel(Xa, Xb, spec)`` — pinning
   ``Phi diag(lam) Phi^T -> k`` in the property tests.
 

@@ -66,10 +66,11 @@ and declares what it ``supports`` so unsupported specs are refused with a
 clear error up front instead of crashing deep inside kernel preparation:
 
 * ``backend="jnp"``    — scan over row blocks, pure XLA (any device);
-* ``backend="pallas"`` — the streaming fused-fit kernel
-  (``kernels/phi_gram``): feature tiles are generated in VMEM inside the
-  Gram accumulation by the expansion's tile builder, so Phi is never
-  written to HBM — for ANY registered expansion.
+* ``backend="pallas"`` — two Pallas kernels per row chunk
+  (``kernels.ops.fused_fit_moments``): the features kernel writes the
+  chunk's Phi with the expansion's tile function, the Gram kernel adds its
+  moments; a chunk holds at most M (padded) rows, so live memory stays
+  O(M^2) in N — for ANY registered expansion.
 
 The same registry serves ``predict_mean_var`` and the per-shard moment
 accumulation in ``core.distributed``.  ``fit_update`` absorbs new
@@ -684,19 +685,18 @@ def _pallas_streamed_bt(X, Y, consts, table, spec, tile):
 
 @jax.jit
 def _pallas_system(X, y, spec: GPSpec, idx, aux):
-    """The scaled system on the streaming fused Pallas kernel: feature
-    tiles are generated on the fly inside the Gram accumulation
-    (kernels/phi_gram) by the expansion's tile builder, so Phi never exists
-    in HBM and peak live memory is O(M^2) in N — one HBM pass over X
-    instead of the materialized path's two passes plus an N x M
-    intermediate.
+    """The scaled system on the Pallas kernels (ops.fused_fit_moments):
+    per row chunk, the features kernel writes the chunk's Phi with the
+    expansion's tile function and the Gram kernel adds its moments, so each
+    feature tile is built once and peak live memory is O(M^2) in N (a
+    chunk holds at most M padded rows).
 
-    Multi-output y (N, T): the shared scaled Gram B comes from the fused
-    kernel exactly as in the single-output case; the per-task moment vectors
-    are streamed block-wise through the expansion feature kernel.  Known
-    cost: this is a SECOND pass over X that regenerates the feature tiles
-    (still O(M T) live memory, never an N x M buffer) — teaching phi_gram
-    to accumulate (M, T) moments in its one pass is the planned follow-up."""
+    Multi-output y (N, T): the shared scaled Gram B comes from the same
+    kernels exactly as in the single-output case; the per-task moment
+    vectors are streamed block-wise through the expansion feature kernel.
+    Known cost: this is a SECOND pass over X that rebuilds the feature
+    tiles (still O(M T) live memory, never an N x M buffer) — teaching the
+    Gram kernel to accumulate (M, T) moments is the planned follow-up."""
     from repro.kernels import ops as kops
 
     exp = get_expansion(spec.expansion)

@@ -6,9 +6,11 @@ The feature kernels are generic over a KernelExpansion's tile builder
 hermite_phi — fused feature construction (generic kernel + the Hermite tile
               for paper Eq. 19)
 rff_phi     — random-Fourier-feature tile builder (RFF-SE / RFF-Matern)
-gram        — fused scaled Gram  B = I + D Phi^T Phi D / sig2
-phi_gram    — streaming fused fit: feature tiles generated inside the Gram
-              accumulation (Phi never in HBM); B and b in one pass
+gram        — the fit's Gram moments  B = I + D Phi^T Phi D / sig2 and
+              b = Phi^T y, row chunk by row chunk (ops.fused_fit_moments:
+              each chunk's Phi from hermite_phi, then this kernel)
+phi_gram    — a GP bank's moments, feature tiles built inside the Gram
+              accumulation (no Phi in HBM for any tenant)
 diag_quad   — predictive-variance diagonal without the N* x N* covariance
 knn         — blocked streaming top-k neighbor search (the Vecchia
               conditioning-set builder; no N x N distance matrix)

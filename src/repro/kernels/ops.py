@@ -15,13 +15,14 @@ import numpy as np
 
 from . import ref
 from .diag_quad import diag_quad_kernel
-from .gram import scaled_gram_kernel
+from .gram import gram_moments_kernel
 from .hermite_phi import hermite_phi_kernel, phi_tile
-from .phi_gram import bank_phi_gram_kernel, phi_gram_kernel
+from .phi_gram import bank_phi_gram_kernel
 
 __all__ = [
     "expansion_phi", "hermite_phi", "scaled_gram", "diag_quad",
-    "fused_fit_moments", "bank_fused_fit_moments", "resolve_interpret",
+    "fused_fit_moments", "fit_chunks", "bank_fused_fit_moments",
+    "resolve_interpret",
 ]
 
 
@@ -108,6 +109,25 @@ def hermite_phi(
     )
 
 
+def fit_chunks(N: int, M: int, block_m: int = 512,
+               block_k: int = 512) -> tuple[int, int, int, int]:
+    """The row-chunk plan of :func:`fused_fit_moments` for N rows and M
+    features: (block_m, block_k, chunks, rows per chunk).
+
+    M is padded to block_m, a power of two from 128 up; the row block
+    block_k is a power of two no larger than block_m, so it divides the
+    padded M.  The rows are cut into ceil(N padded / M padded) chunks of
+    equal size, rounded up to the row block, so a chunk of Phi is never
+    larger than the M x M output that the fit holds anyway."""
+    block_m = min(block_m, max(128, 1 << (M - 1).bit_length()))
+    block_k = min(block_k, block_m, max(8, 1 << (N - 1).bit_length()))
+    Mp = -(-M // block_m) * block_m
+    Np = -(-N // block_k) * block_k
+    chunks = -(-Np // Mp)
+    rows = -(-Np // (chunks * block_k)) * block_k
+    return block_m, block_k, chunks, rows
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n_max", "block_m", "block_k", "scale", "interpret",
@@ -123,41 +143,73 @@ def fused_fit_moments(
     mask: jax.Array | None = None,  # (N,) row validity; None = all valid
     *,
     n_max: int,
-    block_m: int = 256,
-    block_k: int = 256,
+    block_m: int = 512,
+    block_k: int = 512,
     scale: bool = True,
     interpret: bool | None = None,
     tile_fn=phi_tile,
 ) -> tuple[jax.Array, jax.Array]:
-    """Streaming fused fit statistics: Phi is generated tile-by-tile inside
-    the Gram contraction and never written to HBM (kernels/phi_gram),
-    generic over the expansion's ``tile_fn``.
+    """The fit's moments, generic over the expansion's ``tile_fn``:
 
     scale=True  -> (B, b) with B = I + D Phi^T Phi D / sig2  (the fit solve)
     scale=False -> (G, b) with G = Phi^T Phi  (raw moments, e.g. for the
                    distributed per-shard partial sums that are psum'd first)
 
-    ``mask`` excludes rows (e.g. shard padding) from both statistics.
+    Two Pallas calls per row chunk (:func:`fit_chunks`), issued here so
+    that the profiler names both after this function: the features kernel
+    (kernels/hermite_phi) writes the chunk's Phi (rows, M) to HBM once, and
+    the Gram kernel (kernels/gram) adds the chunk's Phi^T Phi and Phi^T y
+    to the moments, scaling them after the last chunk.  Each feature tile
+    is built once per fit.  A kernel that rebuilt the tiles inside every
+    Gram block instead spent about four fifths of its MXU time on them:
+    the Hermite tile's one-hot gather is a contraction only n_max deep,
+    and it still takes a full pass of the MXU's depth.  A chunk holds at
+    most M padded rows, so Phi exists whole only where N <= M, and live
+    memory is O(M^2) in N.
+
+    ``mask`` excludes rows (e.g. shard padding) from both statistics; the
+    padded rows past N are masked the same way (phi(0) != 0).
     """
     N, p = X.shape
     M = S.shape[1]
     interp = resolve_interpret(interpret)
-    block_k = min(block_k, max(8, 1 << (N - 1).bit_length()))
-    block_m = min(block_m, max(128, 1 << (M - 1).bit_length()))
-    Xt = _pad_to(X.T.astype(jnp.float32), 1, block_k)
-    Sp = _pad_to(S.astype(jnp.float32), 1, block_m)
-    d = _pad_to(sqrtlam.reshape(1, -1).astype(jnp.float32), 1, block_m)
-    yp = _pad_to(y.reshape(1, -1).astype(jnp.float32), 1, block_k)
+    block_m, block_k, nc, R = fit_chunks(N, M, block_m, block_k)
+    Np = nc * R
+    Xc = _pad_to(X.astype(jnp.float32), 0, Np).reshape(nc, R, p)
+    Xc = jnp.swapaxes(Xc, 1, 2)                         # (nc, p, R)
+    yc = _pad_to(y.reshape(1, -1).astype(jnp.float32), 1, Np)
     if mask is None:
         mask = jnp.ones((1, N), jnp.float32)
-    else:
-        mask = mask.reshape(1, -1).astype(jnp.float32)
-    mask = _pad_to(mask, 1, block_k)
-    B, b = phi_gram_kernel(
-        Xt, consts, Sp, d, jnp.asarray(sig2, jnp.float32).reshape(1, 1),
-        yp, mask, n_max=n_max, block_m=block_m, block_k=block_k,
-        scale=scale, interpret=interp, tile_fn=tile_fn,
-    )
+    mc = _pad_to(mask.reshape(1, -1).astype(jnp.float32), 1, Np)
+    chunks = (Xc, yc.reshape(nc, 1, R), mc.reshape(nc, 1, R))
+    Sp = _pad_to(S.astype(jnp.float32), 1, block_m)
+    d = _pad_to(sqrtlam.reshape(1, -1).astype(jnp.float32), 1, block_m)
+    s2 = jnp.asarray(sig2, jnp.float32).reshape(1, 1)
+
+    # XLA names a Pallas call after its innermost named scope, which in the
+    # scan's body would be ``closed_call``; trace readers find the fit's
+    # kernels by this function's name
+    @functools.partial(jax.named_call, name="fused_fit_moments")
+    def moments(acc, chunk, last):
+        xt, yk, mk = chunk
+        phi = hermite_phi_kernel(
+            xt, consts, Sp, n_max=n_max, block_n=min(256, block_k),
+            block_m=min(256, block_m), interpret=interp, tile_fn=tile_fn,
+        )
+        return gram_moments_kernel(
+            phi, yk, mk, d, s2, acc, block_m=block_m, block_k=block_k,
+            scale=scale and last, interpret=interp,
+        )
+
+    acc = None
+    if nc > 1:
+        Mp = Sp.shape[1]
+        acc = (jnp.zeros((Mp, Mp), jnp.float32), jnp.zeros((1, Mp), jnp.float32))
+        acc, _ = jax.lax.scan(
+            lambda acc, chunk: (moments(acc, chunk, False), None), acc,
+            jax.tree.map(lambda a: a[:-1], chunks),
+        )
+    B, b = moments(acc, jax.tree.map(lambda a: a[-1], chunks), True)
     # padded feature columns are garbage in general (zero for the Hermite
     # one-hot, cos(0)=1 for RFF) but live entirely in rows/cols >= M of the
     # outputs; the slice below removes every trace of them
@@ -231,8 +283,10 @@ def scaled_gram(
     # columns of d produce identity rows/cols that are sliced away.
     Phip = _pad_to(_pad_to(Phi, 0, block_k), 1, block_m)
     d = _pad_to(sqrtlam.reshape(1, -1).astype(jnp.float32), 1, block_m)
-    out = scaled_gram_kernel(
-        Phip, d, jnp.asarray(sig2, jnp.float32).reshape(1, 1),
+    Np = Phip.shape[0]
+    out, _ = gram_moments_kernel(
+        Phip, jnp.zeros((1, Np), jnp.float32), jnp.ones((1, Np), jnp.float32),
+        d, jnp.asarray(sig2, jnp.float32).reshape(1, 1),
         block_m=block_m, block_k=block_k, interpret=interp,
     )
     return out[:M, :M]

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
-    "ref_phi", "ref_scaled_gram", "ref_diag_quad", "ref_fused_fit_moments",
+    "ref_phi", "ref_rff_phi", "ref_scaled_gram", "ref_diag_quad",
+    "ref_fused_fit_moments",
     "one_hot_selection", "phi_consts",
 ]
 
@@ -52,6 +53,14 @@ def ref_phi(Xt: jax.Array, consts: jax.Array, S: jax.Array, n_max: int) -> jax.A
     return out
 
 
+def ref_rff_phi(Xt: jax.Array, consts, table: jax.Array, n_max: int = 0) -> jax.Array:
+    """Oracle for the RFF tile function (kernels/rff_phi.rff_tile):
+    (p, N) inputs and the (p+1, M) [W; phase] table -> cos(X W + phase).
+    ``consts`` and ``n_max`` keep ref_phi's signature and are unused."""
+    p = Xt.shape[0]
+    return jnp.cos(Xt.T @ table[:p] + table[p])
+
+
 def ref_scaled_gram(Phi: jax.Array, d: jax.Array, sig2) -> jax.Array:
     """Oracle for scaled_gram_kernel: I + D (Phi^T Phi) D / sig2."""
     M = Phi.shape[1]
@@ -60,10 +69,13 @@ def ref_scaled_gram(Phi: jax.Array, d: jax.Array, sig2) -> jax.Array:
     return jnp.eye(M, dtype=jnp.float32) + d[:, None] * G * d[None, :] / sig2
 
 
-def ref_fused_fit_moments(X, y, consts, S, d, sig2, n_max: int, scale=True):
-    """Oracle for the streaming fused fit: materializes Phi (the very thing
-    the kernel avoids), then reduces.  Returns (B, b) or (G, b)."""
-    Phi = ref_phi(X.T.astype(jnp.float32), consts, S, n_max)
+def ref_fused_fit_moments(X, y, consts, S, d, sig2, n_max: int, scale=True,
+                          phi=ref_phi):
+    """Oracle for the fit's moments: materializes the whole Phi (which
+    the kernels never hold), then reduces.  ``phi`` is the feature oracle
+    (ref_phi for Hermite-Mercer, ref_rff_phi for RFF).  Returns (B, b) or
+    (G, b)."""
+    Phi = phi(X.T.astype(jnp.float32), consts, S, n_max)
     b = Phi.T @ y.astype(jnp.float32)
     if not scale:
         return Phi.T @ Phi, b

@@ -15,8 +15,8 @@ scaled frequency matrix W (p, M) over the phase row (1, M), giving a
 is unused (a (1, 1) placeholder keeps the shared kernel signature).  One
 (TK, TM) tile of Phi is then a single MXU contraction xt^T @ W_block plus a
 VPU cosine — O(p) VMEM state per column, no N x M intermediate anywhere,
-which is exactly what lets the streaming fused-fit kernel (phi_gram) run
-RFF fits without materializing Phi.
+which lets the feature kernel build the fit's row chunks of Phi and the
+bank's fused kernel (phi_gram) build tiles in VMEM for RFF fits alike.
 
 The frequencies themselves are *data* (they carry the lengthscale scaling
 sqrt(2) * eps, differentiable for NLML learning) and are built outside the

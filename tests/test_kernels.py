@@ -25,6 +25,25 @@ def _setup(N, p, n_max, kind="full", degree=None, seed=0):
     return X, eps, rho, idx, consts, S
 
 
+def _phi_float64(X, consts, S, n_max):
+    """Hermite-Mercer Phi in float64 NumPy: the recurrence of
+    core.mercer.hermite_psi_rows on the same (float32) inputs."""
+    X, c, S = (np.asarray(a, np.float64) for a in (X, consts, S))
+    out = np.ones((X.shape[0], S.shape[1]))
+    for j, (beta, delta2, zscale) in enumerate(c):
+        x = X[:, j]
+        z = zscale * x
+        psi = [np.sqrt(beta) * np.ones_like(z)]
+        if n_max > 1:
+            psi.append(np.sqrt(2.0) * z * psi[0])
+        for i in range(2, n_max):
+            psi.append(np.sqrt(2.0 / i) * z * psi[-1]
+                       - np.sqrt((i - 1.0) / i) * psi[-2])
+        feats = np.stack(psi, axis=-1) * np.exp(-delta2 * x * x)[:, None]
+        out *= feats @ S[j * n_max:(j + 1) * n_max]
+    return out
+
+
 class TestResolveInterpret:
     @pytest.mark.parametrize("backend,expected", [("cpu", True),
                                                   ("tpu", False)])
@@ -93,10 +112,19 @@ class TestHermitePhi:
     )
     @settings(max_examples=12, deadline=None)
     def test_property_random_shapes(self, N, p, n_max, seed):
+        """Against the recurrence in float64: the depth-scaled rtol of
+        test_matches_ref, plus 8 f32 ulps of the row's largest |Phi| for
+        entries near a root of a Hermite polynomial, where the f32
+        recurrence's absolute error is set by the terms that cancel."""
         X, eps, rho, idx, consts, S = _setup(N, p, n_max, seed=seed)
-        out = ops.hermite_phi(X, consts, S, n_max=n_max)
-        expect = ref.ref_phi(X.T, consts, S, n_max)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=2e-4, atol=1e-5)
+        out = np.asarray(ops.hermite_phi(X, consts, S, n_max=n_max), np.float64)
+        expect = _phi_float64(X, consts, S, n_max)
+        atol = 8 * np.finfo(np.float32).eps * np.abs(expect).max(axis=1, keepdims=True)
+        bad = np.abs(out - expect) > 4e-5 * max(4, n_max) * np.abs(expect) + atol
+        assert not bad.any(), (
+            f"{bad.sum()} of {bad.size} entries off; worst gap "
+            f"{np.abs(out - expect)[bad].max():.3g}"
+        )
 
 
 class TestScaledGram:

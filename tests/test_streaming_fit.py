@@ -1,8 +1,9 @@
 """Tests for the streaming fused-fit engine and online incremental fitting.
 
 Pins the three contracts of the tentpole:
-  1. the fused phi+gram kernel (kernels/phi_gram) == materialize-then-reduce
-     oracle, including row masks and ragged shapes;
+  1. the fit's moments (ops.fused_fit_moments: features and Gram kernels,
+     row chunk by row chunk) == materialize-then-reduce oracle, including
+     row masks, ragged shapes and several chunks;
   2. fit(backend='pallas') materializes NO N x M intermediate (jaxpr sweep)
      while agreeing with the jnp scan fit to f32 tolerance;
   3. fit_update (rank-k Cholesky update) == full refit, for both hybrid
@@ -19,6 +20,8 @@ import pytest
 from repro.core import fagp, mercer
 from repro.data import make_gp_dataset
 from repro.kernels import ops, ref
+from repro.kernels.hermite_phi import phi_tile
+from repro.kernels.rff_phi import rff_consts_placeholder, rff_tile
 
 
 def _setup(N, p, n_max, seed=0):
@@ -77,6 +80,37 @@ class TestFusedFitKernel:
         B, b = ops.fused_fit_moments(X, y, consts, S, d, sig2, mask, n_max=6)
         Be, be = ref.ref_fused_fit_moments(
             X[keep], y[keep], consts, S, d, sig2, 6
+        )
+        np.testing.assert_allclose(np.asarray(B), np.asarray(Be), rtol=1e-3, atol=1e-3)
+        np.testing.assert_allclose(np.asarray(b), np.asarray(be), rtol=1e-3, atol=1e-3)
+
+    @pytest.mark.parametrize("scale", [True, False])
+    @pytest.mark.parametrize("expansion", ["hermite", "rff"])
+    def test_row_chunks_match_oracle(self, expansion, scale):
+        """N > padded M: the rows run through the two kernels in several
+        chunks (600 rows, M padded to 128: five chunks of 128, the last
+        with 40 padded rows), with masked rows across a chunk boundary."""
+        N, p, n = 600, 2, 6
+        X, y, consts, S, d = _setup(N, p, n)
+        phi, tile = ref.ref_phi, phi_tile
+        if expansion == "rff":
+            rng = np.random.default_rng(4)
+            w = rng.standard_normal((p, 24)).astype(np.float32)
+            phase = np.repeat(np.float32([0.0, -np.pi / 2]), 24)
+            S = jnp.asarray(np.vstack([np.hstack([w, w]), phase]))
+            consts = rff_consts_placeholder()
+            d = jnp.asarray(np.geomspace(1.0, 1e-2, 48).astype(np.float32))
+            phi, tile = ref.ref_rff_phi, rff_tile
+        M = S.shape[1]
+        assert ops.fit_chunks(N, M)[2] >= 3
+        keep = np.random.default_rng(6).uniform(size=N) > 0.2
+        keep[200:300] = False                        # chunks 1 and 2
+        mask = jnp.asarray(keep.astype(np.float32))
+        sig2 = jnp.float32(0.02)
+        B, b = ops.fused_fit_moments(X, y, consts, S, d, sig2, mask,
+                                     n_max=n, scale=scale, tile_fn=tile)
+        Be, be = ref.ref_fused_fit_moments(
+            X[keep], y[keep], consts, S, d, sig2, n, scale=scale, phi=phi,
         )
         np.testing.assert_allclose(np.asarray(B), np.asarray(Be), rtol=1e-3, atol=1e-3)
         np.testing.assert_allclose(np.asarray(b), np.asarray(be), rtol=1e-3, atol=1e-3)

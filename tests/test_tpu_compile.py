@@ -7,20 +7,25 @@ the raw kernels (``interpret=False``; the ``ops`` wrappers would pick
 interpret mode from the CPU backend) to the TPU compiler for one chip of
 a described ``v5e:2x2`` topology, at a small width and at the paper's
 width (p=4, n=11: M=14641 padded to 14848, N=10240), and check that the
-compiled program holds the kernel.  Nothing runs; no chip is needed.
+compiled program holds the kernel.  The fit's two phases are compiled
+through ``ops.fused_fit_moments`` with ``interpret=False``: at the small
+width its rows run in eight chunks (the accumulating Gram under a scan),
+at the paper's width in one.  Nothing runs; no chip is needed.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.diag_quad import diag_quad_kernel
-from repro.kernels.gram import scaled_gram_kernel
+from repro.kernels.gram import gram_moments_kernel
 from repro.kernels.hermite_phi import hermite_phi_kernel, phi_tile
-from repro.kernels.phi_gram import bank_phi_gram_kernel, phi_gram_kernel
+from repro.kernels.phi_gram import bank_phi_gram_kernel
 from repro.kernels.rff_phi import rff_tile
 
 # (p, n_max, M, N): M and N already padded to the kernels' block multiples
@@ -62,14 +67,13 @@ def _args(sharding, *shapes):
 def _kernel_call(name: str, width: str, sharding):
     """(kernel with its static arguments bound, argument shapes)."""
     p, n, M, N = WIDTHS[width]
-    if name == "phi_gram_hermite":
-        fn = functools.partial(phi_gram_kernel, n_max=n, interpret=False,
-                               tile_fn=phi_tile)
-        shapes = ((p, N), (p, 3), (p * n, M), (1, M), (1, 1), (1, N), (1, N))
-    elif name == "phi_gram_rff":
-        fn = functools.partial(phi_gram_kernel, n_max=n, interpret=False,
-                               tile_fn=rff_tile)
-        shapes = ((p, N), (1, 1), (p + 1, M), (1, M), (1, 1), (1, N), (1, N))
+    if name in ("fused_fit_hermite", "fused_fit_rff"):
+        tile = phi_tile if name == "fused_fit_hermite" else rff_tile
+        fn = functools.partial(ops.fused_fit_moments, n_max=n,
+                               interpret=False, tile_fn=tile)
+        table = (p * n, M) if tile is phi_tile else (p + 1, M)
+        consts = (p, 3) if tile is phi_tile else (1, 1)
+        shapes = ((N, p), (N,), consts, table, (M,), ())
     elif name == "bank_phi_gram":
         B = BANK[width]
         fn = functools.partial(bank_phi_gram_kernel, n_max=n,
@@ -82,8 +86,18 @@ def _kernel_call(name: str, width: str, sharding):
         fn = functools.partial(diag_quad_kernel, interpret=False)
         shapes = ((N, M), (M, M))
     elif name == "scaled_gram":
-        fn = functools.partial(scaled_gram_kernel, interpret=False)
-        shapes = ((N, M), (1, M), (1, 1))
+        fn = functools.partial(gram_moments_kernel, interpret=False)
+        shapes = ((N, M), (1, N), (1, N), (1, M), (1, 1))
+    elif name == "gram_moments_chunk":
+        # a middle row chunk of the fit: raw moments added in place
+        block_m, block_k, _, rows = ops.fit_chunks(N, M)
+        fn = functools.partial(gram_moments_kernel, block_m=block_m,
+                               block_k=block_k, scale=False,
+                               interpret=False)
+        args = _args(sharding, (rows, M), (1, rows), (1, rows), (1, M),
+                     (1, 1))
+        return (lambda *a: fn(*a[:5], acc=a[5:]),
+                args + _args(sharding, (M, M), (1, M)))
     else:
         raise ValueError(name)
     return fn, _args(sharding, *shapes)
@@ -91,10 +105,26 @@ def _kernel_call(name: str, width: str, sharding):
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
 @pytest.mark.parametrize("name", [
-    "phi_gram_hermite", "phi_gram_rff", "bank_phi_gram", "hermite_phi",
-    "diag_quad", "scaled_gram",
+    "fused_fit_hermite", "fused_fit_rff", "bank_phi_gram", "hermite_phi",
+    "diag_quad", "scaled_gram", "gram_moments_chunk",
 ])
 def test_kernel_compiles_for_v5e(one_chip, name, width):
     fn, args = _kernel_call(name, width, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_fused_fit_kernels_carry_its_name(one_chip, width):
+    """Both phases' Pallas calls, in every row chunk, are named after
+    ``fused_fit_moments``: the trace reader of the fit's roofline
+    (bench/layers/phi_gram_roofline.py) finds its ops by that name."""
+    p, n, _, N = WIDTHS[width]
+    M = n ** p
+    fn = functools.partial(ops.fused_fit_moments, n_max=n, interpret=False)
+    args = _args(one_chip, (N, p), (N,), (p, 3), (p * n, M), (M,), ())
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = re.findall(r"^\s*(?:ROOT )?(%?[\w.-]+) = .*custom_call_target=\"tpu_custom_call\"",
+                       text, re.M)
+    assert len(names) >= 2, names
+    assert all(re.match(r"%?fused_fit_moments\.\d+$", x) for x in names), names
