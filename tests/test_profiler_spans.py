@@ -16,10 +16,15 @@ Pinned here, on the CPU at toy size, for both built-in backends:
 * no span carries arguments: the profiler parses a ``name#key=value#``
   suffix into the event's stats, and a span with arguments would give
   every call a different name in any trace viewer;
-* a ``Tracer`` span lands both in ``Tracer.events()`` and in the trace.
+* a ``Tracer`` span lands both in ``Tracer.events()`` and in the trace;
+* ``fagp.posterior.unprepared`` (the serving solve inverting the factor's
+  diagonal blocks in-call) opens inside ``fagp.posterior`` once per
+  ``mean_var`` on a state without prepared blocks, and never on a state
+  from ``GP.fit``.
 """
 from __future__ import annotations
 
+import dataclasses
 import glob
 import os
 
@@ -122,6 +127,36 @@ def test_spans_carry_no_arguments(gp_events):
     for name, _, _, stats in gp_events:
         if name.split("#")[0] in NAMES:
             assert "#" not in name and not stats, (name, stats)
+
+
+def test_fitted_state_serves_prepared(gp_events):
+    _, gp_events = gp_events
+    assert not _named(gp_events, "fagp.posterior.unprepared")
+
+
+@pytest.mark.parametrize("backend", ["pallas", "jnp"])
+def test_unprepared_state_opens_its_span_once_per_call(backend, tmp_path):
+    rng = np.random.default_rng(1)
+    X = jnp.asarray(rng.uniform(-1, 1, (64, 2)), jnp.float32)
+    y = jnp.sin(3 * X[:, 0])
+    Xs = jnp.asarray(rng.uniform(-1, 1, (16, 2)), jnp.float32)
+    spec = GPSpec.create(4, eps=[0.8, 0.8], rho=2.0, noise=0.05,
+                         backend=backend)
+    gp = GP.fit(X, y, spec)
+    bare = GP.from_state(dataclasses.replace(gp.state, chol_dinv=None))
+    jax.block_until_ready(bare.mean_var(Xs))          # compile outside
+
+    def work():
+        for _ in range(3):
+            jax.block_until_ready(bare.mean_var(Xs))
+
+    events = _host_events(work, tmp_path)
+    inner = _named(events, "fagp.posterior.unprepared")
+    outer = _named(events, "fagp.posterior")
+    assert len(inner) == len(outer) == len(_named(events, "gp.mean_var")) == 3
+    for (_, c0, c1, _), (_, p0, p1, _) in zip(sorted(inner, key=lambda e: e[1]),
+                                              sorted(outer, key=lambda e: e[1])):
+        assert p0 <= c0 and c1 <= p1
 
 
 def test_tracer_span_lands_in_events_and_profiler_trace(tmp_path):

@@ -11,6 +11,11 @@ compiled program holds the kernel.  The fit's two phases are compiled
 through ``ops.fused_fit_moments`` with ``interpret=False``: at the small
 width its rows run in eight chunks (the accumulating Gram under a scan),
 at the paper's width in one.  Nothing runs; no chip is needed.
+
+The serving solve is pinned beside them: with the fit's prepared factor
+``_posterior_mean_var`` stays one jit of that name (the benchmark's
+trace readers match ``jit__posterior_mean_var``) and neither inverts L's
+diagonal blocks nor passes over the whole M x M factor per call.
 """
 import functools
 import os
@@ -21,6 +26,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import fagp
 from repro.kernels import ops
 from repro.kernels.diag_quad import diag_quad_kernel
 from repro.kernels.gram import gram_moments_kernel
@@ -128,3 +134,49 @@ def test_fused_fit_kernels_carry_its_name(one_chip, width):
                        text, re.M)
     assert len(names) >= 2, names
     assert all(re.match(r"%?fused_fit_moments\.\d+$", x) for x in names), names
+
+
+def _serving_args(M, Q, prepared, sharding=None):
+    """Argument shapes of ``_posterior_mean_var`` for an M-feature model
+    and Q query rows, with the fit's prepared blocks or without."""
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    tb = fagp._solve_block(M)
+    dinv = f32(-(-M // tb), tb, tb) if prepared else None
+    return f32(M), (f32(M, M), dinv), f32(M), f32(Q, M)
+
+
+def _primitives(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its params."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("prepared", [True, False])
+def test_serving_solve_is_one_jit_and_reinverts_only_unprepared(prepared):
+    args = _serving_args(1000, 64, prepared)
+    closed = jax.make_jaxpr(lambda *a: fagp._posterior_mean_var(*a))(*args)
+    (call,) = closed.jaxpr.eqns
+    assert call.params["name"] == "_posterior_mean_var"
+    names = {e.primitive.name for e in _primitives(closed.jaxpr)}
+    assert ("triangular_solve" in names) == (not prepared), names
+
+
+def test_prepared_serving_solve_compiles_for_v5e(one_chip):
+    """Compiled for the chip at a ragged M (TB = 512 and a last block of
+    45): no ``InvertDiagBlocksLowerTriangular`` custom call and no op whose
+    result is the whole M x M factor."""
+    M = 4141
+    args = _serving_args(M, 256, True, one_chip)
+    text = fagp._posterior_mean_var.lower(*args).compile().as_text()
+    assert "InvertDiagBlocks" not in text
+    whole = [ln for ln in text.splitlines()
+             if re.search(rf"= f32\[{M},{M}\]\S* (?!parameter\()", ln)]
+    assert not whole, whole[:3]
