@@ -61,8 +61,16 @@ VECCHIA_MU_ABS = 0.15
 VECCHIA_VAR_ABS = 0.05
 VECCHIA_N = 10_000
 VECCHIA_SUBSET = 256
+# the clustered-spatial workload, as the former CPU Vecchia benchmark set
+# it (tests/test_vecchia.py::TestClusteredAccuracy pins the same shape at
+# N=1500): bump length scale 0.15 -> eps = 1/(sqrt(2) * 0.15)
+EPS = 4.714
+NOISE = 0.02
+K = 32
+DATA_KW = dict(extent=6.0, length_scale=0.15, noise=0.02, n_bumps=120)
 # --chips 4: sharded bank vs resident bank; the bank's own parity bound
-# for a sharded fit (benchmarks/shard_scaling.py).  The distributed fit
+# for a sharded fit (tests/test_shard_bank.py::TestShardedParity::
+# test_eight_shard_fit_agreement).  The distributed fit
 # is held to the FIT_* bounds: it also differs only in summation order.
 SHARD_ABS = 5e-5
 
@@ -362,7 +370,6 @@ def phase_fleet(kind: str) -> bool:
 def phase_vecchia(kind: str) -> bool:
     import jax.numpy as jnp
 
-    from benchmarks.vecchia import DATA_KW, EPS, K, NOISE
     from repro.core import exact_gp
     from repro.core.gp import GP, GPSpec
     from repro.core.mercer import SEKernelParams

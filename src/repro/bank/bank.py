@@ -882,7 +882,8 @@ class GPBank:
         windows) — each under its own slot's hyperparameters, per-slot
         eigenvalue rows rewritten.  The fallback for downdates that lost
         positive definiteness, and the exact semantic reference the
-        downdate is gated against (<= 1e-5, benchmarks/tenant_churn.py)."""
+        downdate is held to (<= 1e-5, tests/test_lifecycle.py::
+        TestForgetting::test_downdate_matches_refit_on_retained_window)."""
         Xw = jnp.asarray(Xw)
         yw = jnp.asarray(yw)
         if Xw.ndim != 3 or yw.shape != Xw.shape[:2]:
@@ -1043,8 +1044,9 @@ class GPBank:
         tenant is stepped by ONE compiled AdamW step per iteration — a
         Python loop of per-tenant ``GP.optimize`` runs pays per-step
         dispatch B times and lands on EXACTLY the same hyperparameters (the
-        per-tenant lane math is bit-identical by construction; the <= 1e-5
-        parity gate is asserted in benchmarks/gp_hyperopt.py).
+        per-tenant lane math is bit-identical by construction; the parity
+        is pinned in tests/test_gp_hyperopt.py::TestGPBankOptimize::
+        test_bank_optimize_matches_gp_loop).
 
         Xb (B, N, p) / yb (B, N) carry each tenant's training data in the
         row order of ``tenant_ids`` (default: every active tenant in

@@ -424,8 +424,8 @@ class FleetEngine:
         now = self._clock()
         ticket = self.router.submit(tenant, x)
         # admit telemetry: a plain int plus a 1-in-256-sampled trace
-        # event — submit is the per-query hot path and the overhead gate
-        # in BENCH_obs.json (<=1.05x) rules out a full span per query
+        # event — submit is the per-query hot path, too hot for a full
+        # span per query
         self._n_admitted += 1
         if self._trace_on and not (self._n_admitted & 255):
             self.tracer.instant("admit", tenant=str(tenant), depth=pending)

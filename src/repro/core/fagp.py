@@ -55,7 +55,8 @@ Two mathematically identical posterior evaluation paths are provided:
 
   which avoids every N x N / N* x N intermediate (O(N M) -> O(M^2) memory,
   and ~N/M fewer FLOPs for the covariance).  Tests assert the two modes
-  agree to f32 tolerance; EXPERIMENTS.md §Perf reports them separately.
+  agree to f32 tolerance (tests/test_fagp.py::TestPosterior::
+  test_paper_mode_equals_fused_mode).
 
 Both paths share ``fit``, which accumulates the two sufficient statistics
 G = Phi^T Phi and b = Phi^T y in one streaming pass — constant memory in N

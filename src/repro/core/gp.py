@@ -20,8 +20,8 @@ object instead of six free functions that each re-take configuration:
 results or a new `GP`.  Multi-output targets ``y`` of shape ``(N, T)``
 share one factorization with per-task mean weights — ``predict``/
 ``mean_var`` then return ``(N*, T)`` means and a shared variance.
-`serve_gp`, `core.distributed` and the benchmarks all speak this one
-interface.
+`serve_gp`, `core.distributed` and the chip benchmark (`bench/`) all
+speak this one interface.
 
 TWO things are pluggable behind the facade, at different layers:
 

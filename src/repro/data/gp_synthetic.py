@@ -4,7 +4,8 @@
 
 The paper's bash script generates these with increasing n and p at fixed
 N = 10^4; ``make_gp_dataset`` is the same generator as a pure function
-(deterministic in seed), used by the benchmarks and examples.
+(deterministic in seed), used by the tests, the serving loops and the
+examples.
 """
 from __future__ import annotations
 

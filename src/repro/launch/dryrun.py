@@ -11,7 +11,7 @@ For each cell this proves, without hardware:
   * the sharding config is coherent (no mismatched collectives),
   * the per-device memory footprint (memory_analysis),
   * the FLOP/byte/collective composition (cost_analysis + HLO parse),
-and records a JSON blob consumed by EXPERIMENTS.md §Dry-run / §Roofline.
+and records a JSON blob under experiments/dryrun/.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch all --shape all --mesh both

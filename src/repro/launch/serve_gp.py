@@ -9,14 +9,12 @@ Two production shapes of the paper's workload:
   :class:`~repro.bank.GPBank` (one stacked state, one executable for the
   whole fleet) and traffic flows through a :class:`~repro.bank.BankRouter`
   that coalesces per-tenant query/observation queues into padded
-  mixed-tenant microbatches.  By default the router is driven by the
-  pipelined :class:`~repro.bank.FleetEngine` (``engine="pipelined"``):
-  dispatch-ahead blocks with no per-tick ``block_until_ready``, per-tenant
-  deadlines answered with the documented timeout sentinel, queue-budget
-  backpressure, arrival-rate-autotuned microbatch buckets, and per-tenant
-  p50/p99 + sustained-QPS metrics in the returned history.
-  ``engine="sync"`` keeps the strict coalesce -> dispatch -> block ->
-  respond loop (the baseline ``benchmarks/serve_latency.py`` beats).
+  mixed-tenant microbatches.  The router is driven by the pipelined
+  :class:`~repro.bank.FleetEngine`: dispatch-ahead blocks with no per-tick
+  ``block_until_ready``, per-tenant deadlines answered with the documented
+  timeout sentinel, queue-budget backpressure, arrival-rate-autotuned
+  microbatch buckets, and per-tenant p50/p99 + sustained-QPS metrics in
+  the returned history.
 
 Both loops speak self-describing sessions: the spec (index set, backend,
 block size) is baked in at fit time, so neither the query path nor the
@@ -160,7 +158,6 @@ def serve_fleet(
     reopt_min_rows: int = 16,
     reopt_steps: int = 25,
     reopt_restarts: int = 2,
-    engine: str = "pipelined",
     max_in_flight: int = 4,
     queue_budget: int = 4096,
     slo_s: float | None = None,
@@ -176,20 +173,17 @@ def serve_fleet(
 
     Each tenant observes its own shifted copy of the synthetic target.
     Every round, mixed-tenant query traffic (uniformly random tenant per
-    query) flows through the serving frontend in padded microbatches, and
-    per-tenant observation streams are absorbed with batched
+    query) flows through a :class:`~repro.bank.FleetEngine` in padded
+    microbatches — queries dispatch ahead while the host packs the next
+    block, expired tickets (``slo_s``) get the timeout sentinel instead of
+    a seat in a padded block, and the block size autotunes to the arrival
+    rate — and per-tenant observation streams are absorbed with batched
     ``GPBank.update`` rounds.  Reported per round: ingest time, query
     wall time, fleet-wide queries/s, timeout count, and RMSE against each
     tenant's own target; the returned dict additionally carries the
     engine's cumulative latency metrics (per-tenant p50/p99, sustained
-    QPS, bucket usage) when ``engine="pipelined"``.
-
-    ``engine`` selects the serving frontend: ``"pipelined"`` (default)
-    drives a :class:`~repro.bank.FleetEngine` — queries dispatch ahead
-    while the host packs the next block, expired tickets (``slo_s``) get
-    the timeout sentinel instead of a seat in a padded block, and the
-    block size autotunes to the arrival rate; ``"sync"`` is the strict
-    submit-all / flush / block loop.
+    QPS, bucket usage, and the registry snapshot when ``metrics`` is
+    live) under ``latency``.
 
     ``reopt_every > 0`` additionally re-optimizes STALE tenants every that
     many rounds: tenants that absorbed >= ``reopt_min_rows`` observations
@@ -198,10 +192,10 @@ def serve_fleet(
     (``router.reoptimize``) — the bank becomes heterogeneous and each
     tenant serves under its own learned hyperparameters.
 
-    ``cold_dir`` turns the fleet ELASTIC (pipelined engine only): the
-    bank becomes a :class:`~repro.bank.TieredBank` with ``capacity`` hot
-    slots (default: all tenants resident) fronting versioned per-tenant
-    checkpoints under ``cold_dir`` — traffic to cold tenants warm-restores
+    ``cold_dir`` turns the fleet ELASTIC: the bank becomes a
+    :class:`~repro.bank.TieredBank` with ``capacity`` hot slots (default:
+    all tenants resident) fronting versioned per-tenant checkpoints under
+    ``cold_dir`` — traffic to cold tenants warm-restores
     them through the engine, evicting LRU tenants back to disk, with zero
     new executables across the churn.  ``window > 0`` additionally ages
     drifted tenants before re-optimization: everything older than each
@@ -247,15 +241,6 @@ def serve_fleet(
         yb[t] = np.asarray(y_all[:n_train])
         pools.append((np.asarray(X_all), np.asarray(y_all)))
 
-    if engine not in ("pipelined", "sync"):
-        raise ValueError(
-            f"engine must be 'pipelined' or 'sync', got {engine!r}"
-        )
-    if cold_dir is not None and engine != "pipelined":
-        raise ValueError(
-            "a tiered fleet (cold_dir) needs the pipelined engine: the "
-            "sync router fail-fasts on cold tenants instead of paging"
-        )
     if (capacity is not None or window) and cold_dir is None:
         raise ValueError(
             "capacity/window need a cold tier; pass cold_dir"
@@ -292,27 +277,24 @@ def serve_fleet(
     router = BankRouter(bank, microbatch=microbatch,
                         ingest_chunk=ingest_chunk,
                         metrics=metrics, tracer=tracer)
-    eng = None
-    if engine == "pipelined":
-        eng = FleetEngine(
-            router, max_in_flight=max_in_flight,
-            queue_budget=queue_budget, default_slo_s=slo_s,
-            tiered=tiered,
-            metrics=metrics, tracer=tracer, watchdog=watchdog,
-        )
+    eng = FleetEngine(
+        router, max_in_flight=max_in_flight,
+        queue_budget=queue_budget, default_slo_s=slo_s,
+        tiered=tiered,
+        metrics=metrics, tracer=tracer, watchdog=watchdog,
+    )
     consumed = [n_train] * tenants
     history = []
     for r in range(rounds):
         # -- ingest: each tenant streams a few fresh observations ----------
-        front = eng if eng is not None else router
         for _ in range(observations_per_round):
             t = int(rng.integers(0, tenants))
             X_all, y_all = pools[t]
             i = consumed[t] % X_all.shape[0]
             consumed[t] += 1
-            front.observe(t, X_all[i], y_all[i])
+            eng.observe(t, X_all[i], y_all[i])
         t0 = time.perf_counter()
-        absorbed = front.ingest()
+        absorbed = eng.ingest()
         jax.block_until_ready(router.bank.stack.u)
         t_ingest = time.perf_counter() - t0
 
@@ -374,36 +356,24 @@ def serve_fleet(
         Xq = rng.uniform(-1.0, 1.0, size=(queries_per_round, p)).astype(
             np.float32
         )
-        timeouts = 0
-        if eng is not None:
-            # pipelined: submission itself dispatches blocks ahead
-            # (auto_pump), drain() overlaps packing with device execution
-            t0 = time.perf_counter()
-            tickets = [
-                eng.submit(int(t), Xq[i]) for i, t in enumerate(q_tenants)
-            ]
-            results = eng.drain()
-            t_query = time.perf_counter() - t0
-            served = {
-                tk: i for i, tk in enumerate(tickets)
-                if not results[tk].timed_out
-            }
-            timeouts = len(tickets) - len(served)
-            mu = np.array([results[tk].mu for tk in served])
-            truth = (np.sum(np.cos(Xq), axis=1)
-                     + offsets[q_tenants])[list(served.values())]
-        else:
-            tickets = [
-                router.submit(int(t), Xq[i])
-                for i, t in enumerate(q_tenants)
-            ]
-            t0 = time.perf_counter()
-            results = router.flush()
-            t_query = time.perf_counter() - t0
-            mu = np.array([results[tk][0] for tk in tickets])
-            # RMSE of each query against its own tenant's (noise-free)
-            # Eq. 21 target sum_j cos(x_j) + offset_t
-            truth = np.sum(np.cos(Xq), axis=1) + offsets[q_tenants]
+        # submission itself dispatches blocks ahead (auto_pump), drain()
+        # overlaps packing with device execution
+        t0 = time.perf_counter()
+        tickets = [
+            eng.submit(int(t), Xq[i]) for i, t in enumerate(q_tenants)
+        ]
+        results = eng.drain()
+        t_query = time.perf_counter() - t0
+        served = {
+            tk: i for i, tk in enumerate(tickets)
+            if not results[tk].timed_out
+        }
+        timeouts = len(tickets) - len(served)
+        mu = np.array([results[tk].mu for tk in served])
+        # RMSE of each served query against its own tenant's (noise-free)
+        # Eq. 21 target sum_j cos(x_j) + offset_t
+        truth = (np.sum(np.cos(Xq), axis=1)
+                 + offsets[q_tenants])[list(served.values())]
         rmse = float(np.sqrt(np.mean((mu - truth) ** 2)))
         nb = max(1, (queries_per_round + microbatch - 1) // microbatch)
         history.append({
@@ -411,7 +381,7 @@ def serve_fleet(
             "rows_absorbed": absorbed,
             "ingest_s": t_ingest,
             "query_s": t_query,
-            # one aggregate flush/drain is timed, so this is a
+            # one aggregate drain is timed, so this is a
             # per-microbatch MEAN (serve_gp's predict_p50_s is a true
             # per-block median)
             "query_mean_s": t_query / nb,
@@ -427,17 +397,13 @@ def serve_fleet(
         "tenants": tenants,
         "rounds": history,
         "M": bank.n_features,
-        "engine": engine,
+        "latency": eng.metrics(),
     }
     if shards:
         out["shards"] = shards
         out["shard_occupancy"] = [
             int(c) for c in router.bank.shard_occupancy()
         ]
-    if eng is not None:
-        out["latency"] = eng.metrics()
-    elif metrics is not NULL:
-        out["telemetry"] = metrics.snapshot()
     if tiered is not None:
         out["lifecycle"] = dict(
             tiered.stats, capacity=tiered.capacity,
@@ -446,7 +412,7 @@ def serve_fleet(
     return out
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="jnp",
                     choices=fagp.available_backends())
@@ -461,10 +427,6 @@ def main():
     ap.add_argument("--microbatch", type=int, default=128)
     ap.add_argument("--reopt-every", type=int, default=0, metavar="K",
                     help="re-optimize stale tenants every K serving rounds")
-    ap.add_argument("--engine", default="pipelined",
-                    choices=["pipelined", "sync"],
-                    help="fleet serving frontend (pipelined FleetEngine "
-                         "vs the strict synchronous loop)")
     ap.add_argument("--max-in-flight", type=int, default=4,
                     help="dispatch-ahead depth of the pipelined engine")
     ap.add_argument("--slo", type=float, default=None, metavar="SECONDS",
@@ -475,7 +437,7 @@ def main():
                          "the rest to the cold tier); needs --cold-dir")
     ap.add_argument("--cold-dir", default=None, metavar="DIR",
                     help="cold-tier checkpoint directory (enables the "
-                         "TieredBank lifecycle; pipelined engine only)")
+                         "TieredBank lifecycle)")
     ap.add_argument("--shards", type=int, default=0, metavar="S",
                     help="shard the fleet's tenant axis across an S-way "
                          "'bank' device mesh (needs S visible devices; on "
@@ -497,7 +459,7 @@ def main():
                     choices=["warn", "raise", "count"],
                     help="arm the recompile watchdog over the serving "
                          "executables (fleet mode only)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     enable_compile_cache()
     if args.fleet:
         obs_on = (args.metrics_port is not None or args.trace_out
@@ -523,7 +485,7 @@ def main():
                 queries_per_round=args.queries,
                 observations_per_round=args.update_size,
                 microbatch=args.microbatch, reopt_every=args.reopt_every,
-                engine=args.engine, max_in_flight=args.max_in_flight,
+                max_in_flight=args.max_in_flight,
                 slo_s=args.slo, capacity=args.capacity,
                 cold_dir=args.cold_dir, window=args.window,
                 shards=args.shards,
@@ -539,7 +501,7 @@ def main():
                 obs_metrics.set_default(NULL)
         print(
             f"fleet of {r['tenants']} fitted in {r['fit_s']*1e3:.1f} ms "
-            f"(M={r['M']} each; {r['engine']} engine)"
+            f"(M={r['M']} each)"
         )
         if "shards" in r:
             print(
@@ -559,14 +521,13 @@ def main():
                 f"{'; ' + str(h['timeouts']) + ' timeouts' if h['timeouts'] else ''}"
                 f"{reopt}"
             )
-        if "latency" in r:
-            o = r["latency"]["overall"]
-            print(
-                f"engine: p50 {o['p50_s']*1e3:.2f} ms, p99 "
-                f"{o['p99_s']*1e3:.2f} ms per ticket; sustained "
-                f"{o['sustained_qps']:.0f} q/s; {o['expired']} expired; "
-                f"buckets {sorted(r['latency']['bucket_uses'].items())}"
-            )
+        o = r["latency"]["overall"]
+        print(
+            f"engine: p50 {o['p50_s']*1e3:.2f} ms, p99 "
+            f"{o['p99_s']*1e3:.2f} ms per ticket; sustained "
+            f"{o['sustained_qps']:.0f} q/s; {o['expired']} expired; "
+            f"buckets {sorted(r['latency']['bucket_uses'].items())}"
+        )
         if "lifecycle" in r:
             lc = r["lifecycle"]
             print(

@@ -16,8 +16,7 @@ is instrumented against this registry.  Design constraints, in order:
 * **One schema.** :meth:`MetricsRegistry.snapshot` returns a
   JSON-serializable dict and :meth:`MetricsRegistry.render_prometheus`
   the text exposition format — the same series names either way, so the
-  ``/metrics`` endpoint, ``FleetEngine.metrics()["counters"]`` and
-  ``BENCH_obs.json`` all agree.
+  ``/metrics`` endpoint and ``FleetEngine.metrics()["registry"]`` agree.
 
 Zero third-party dependencies (stdlib only): the checkpoint store and the
 kernel-free host layers import this module freely, in any environment.

@@ -36,8 +36,9 @@ Anatomy of a step (``_lane_step``):
 
 Restart jitter is keyed by ``(seed, restart)`` only — every tenant sees
 the SAME R perturbations of its init — so a loop of single-tenant runs
-with the same seed reproduces the fleet's lanes exactly (the parity gate
-in benchmarks/gp_hyperopt.py compares the two to <= 1e-5).
+with the same seed reproduces the fleet's lanes exactly
+(tests/test_gp_hyperopt.py::TestLaneEngine::
+test_fleet_equals_loop_of_singles_exactly compares the two).
 
 ``optimize_fleet`` drives the loop and selects the best restart per tenant
 by final NLML; ``optimize_restarts`` is the single-model wrapper that

@@ -1,8 +1,9 @@
 """Scan-aware cost extraction from compiled HLO text.
 
 ``compiled.cost_analysis()`` counts a while-loop body ONCE regardless of its
-trip count (verified empirically — see EXPERIMENTS.md §Methodology), which
-undercounts every scanned layer stack by ~n_layers.  This module rebuilds
+trip count (tests/test_roofline.py::TestHloCost::
+test_scan_flops_scaled_by_trip_count), which undercounts every scanned
+layer stack by ~n_layers.  This module rebuilds
 FLOP / byte / collective totals from the HLO text itself:
 
   * split the module into named computations;

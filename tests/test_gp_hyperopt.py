@@ -306,6 +306,8 @@ class TestGPBankOptimize:
             st = opt.state(t)
             np.testing.assert_array_equal(np.asarray(st.spec.eps),
                                           np.asarray(gp.spec.eps))
+            np.testing.assert_array_equal(np.asarray(st.spec.rho),
+                                          np.asarray(gp.spec.rho))
             np.testing.assert_array_equal(np.asarray(st.spec.noise),
                                           np.asarray(gp.spec.noise))
             m1, v1 = gp.mean_var(Xq)

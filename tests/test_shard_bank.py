@@ -109,6 +109,56 @@ class TestShardedParity:
                                        atol=1e-5)
         """, backend=backend)
 
+    def test_eight_shard_fit_agreement(self):
+        run_sub("""
+            # a 1024-tenant fleet across all 8 devices: a sharded FIT of
+            # the same data agrees with the resident fit to 5e-5 (only the
+            # f32 summation order differs), and serving the resident
+            # states sharded matches the resident bank and a loop of
+            # single-model sessions (over 64 tenants) to 1e-5
+            from repro.core.gp import GP
+
+            B = 1024
+            Xb = np.zeros((B, N_ROWS, P), np.float32)
+            yb = np.zeros((B, N_ROWS), np.float32)
+            for s in range(B):
+                X, y, *_ = make_gp_dataset(N_ROWS, P, seed=s)
+                Xb[s], yb[s] = np.asarray(X), np.asarray(y)
+            Xb, yb = jnp.asarray(Xb), jnp.asarray(yb)
+            nq = 512
+            Xq = jnp.asarray(rng.uniform(-1, 1, (nq, P)).astype(np.float32))
+            tenants = np.asarray(rng.integers(0, B, nq))
+            ids = [int(t) for t in tenants]
+            mesh8 = make_bank_mesh(8)
+            resident = GPBank.fit(Xb, yb, spec)
+            mu_r, var_r = resident.mean_var(ids, Xq)
+
+            fitted = ShardedGPBank.fit(Xb, yb, spec, mesh8)
+            mu_f, var_f = fitted.mean_var(ids, Xq)
+            np.testing.assert_allclose(np.asarray(mu_f), np.asarray(mu_r),
+                                       rtol=0, atol=5e-5)
+            np.testing.assert_allclose(np.asarray(var_f), np.asarray(var_r),
+                                       rtol=0, atol=5e-5)
+
+            sharded = ShardedGPBank.from_bank(resident, mesh8)
+            mu_s, var_s = sharded.mean_var(ids, Xq)
+            np.testing.assert_allclose(np.asarray(mu_s), np.asarray(mu_r),
+                                       rtol=0, atol=1e-5)
+            np.testing.assert_allclose(np.asarray(var_s), np.asarray(var_r),
+                                       rtol=0, atol=1e-5)
+            mu_s, var_s = np.asarray(mu_s), np.asarray(var_s)
+            for t in range(64):
+                rows = np.flatnonzero(tenants == t)
+                if rows.size == 0:
+                    continue
+                m1, v1 = GP.from_state(sharded.state(t)).mean_var(
+                    Xq[jnp.asarray(rows)])
+                np.testing.assert_allclose(mu_s[rows], np.asarray(m1),
+                                           rtol=0, atol=1e-5)
+                np.testing.assert_allclose(var_s[rows], np.asarray(v1),
+                                           rtol=0, atol=1e-5)
+        """)
+
     def test_2d_bank_data_mesh_fit(self):
         run_sub("""
             # (bank, data) mesh: the fit row-shards each shard's N axis
@@ -232,6 +282,28 @@ class TestShardedChurn:
 
 
 class TestShardedIntegration:
+    def test_serve_fleet_sharded(self):
+        run_sub("""
+            # the operator's loop with --shards: the fleet spreads evenly
+            # over the mesh, every query is served, and the trace carries
+            # the per-shard dispatch and ingest events
+            from repro.launch.serve_gp import serve_fleet
+            from repro.obs import Tracer
+
+            tracer = Tracer()
+            out = serve_fleet(tenants=16, n_train=16, n=4, rounds=2,
+                              queries_per_round=32,
+                              observations_per_round=16, microbatch=8,
+                              shards=S, tracer=tracer)
+            assert out["shards"] == S
+            assert out["shard_occupancy"] == [B // S] * S
+            for h in out["rounds"]:
+                assert h["timeouts"] == 0 and np.isfinite(h["rmse"])
+            assert out["latency"]["overall"]["completed"] == 64
+            names = {ev["name"] for ev in tracer.events()}
+            assert {"shard_dispatch", "shard_ingest"} <= names
+        """)
+
     def test_router_engine_tiered(self):
         run_sub("""
             import tempfile

@@ -1,13 +1,12 @@
 #!/usr/bin/env python3
-"""Docs reference checker: code references in README.md / EXPERIMENTS.md
-must resolve.
+"""Docs reference checker: code references in README.md must resolve.
 
 Checked reference forms (inside backticks):
   `path/to/file.py`            -> file must exist
   `path/to/file.py::symbol`    -> file must exist AND contain `symbol`
   `dir/`                       -> directory must exist
-  `python -m pkg.mod ...`      -> module must resolve under src/ (or be a
-                                  top-level script dir like benchmarks/)
+  `python -m pkg.mod ...`      -> module must resolve under src/ (or the
+                                  repo root)
 
 Run from anywhere:  python tools/check_docs.py
 Exit code 1 on any dangling reference (CI gate).
@@ -19,7 +18,7 @@ import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
-DOCS = ["README.md", "EXPERIMENTS.md"]
+DOCS = ["README.md"]
 
 # runtime-generated artifacts: docs may reference them before they exist
 ALLOW_MISSING_PREFIXES = ("experiments/",)
@@ -58,7 +57,7 @@ def check(doc: str) -> list[str]:
                 errors.append(f"{doc}: `{m.group(0)[1:-1]}` — {rel} has no '{leaf}'")
     for m in DIRLIKE.finditer(text):
         rel = m.group(1)
-        if "/" in rel.rstrip("/") or rel in ("src/", "tests/", "benchmarks/", "examples/"):
+        if "/" in rel.rstrip("/") or rel in ("src/", "tests/", "bench/", "examples/"):
             if not (ROOT / rel).exists() and not allowed_missing(rel):
                 errors.append(f"{doc}: `{rel}` — missing directory")
     for m in MODLIKE.finditer(text):
